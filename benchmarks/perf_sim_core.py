@@ -23,7 +23,9 @@ Writes ``BENCH_sim_core.json``:
                          same N fifo seeds run numpy-sequentially vs as
                          one jitted batch, per-lane JCT/CCT agreement
                          asserted; headline is the 20-seed pipe_serve
-                         lane (ISSUE-10 gate: >= 5x warm)
+                         lane (gated at >= 5x warm by check_batched);
+                         ``device`` names the platform, device kind and
+                         device count the engine ran on
   notes[]                anything skipped or capped (no silent caps)
 
 All wall times come from ``time.perf_counter()``.
@@ -160,13 +162,20 @@ def run_batched_bench(seeds: int, scenarios=None, smoke: bool = False) -> dict:
     is shared by all lanes) batched walls.  The headline is the
     pipe_serve lane: the paper's headline scenario and the shape where
     the batched step is cheapest relative to numpy's per-event cost."""
+    import jax
+
     from repro.appdag.mixer import SCENARIOS, build_scenario
     from repro.core.simjax import pack_instance, run_fifo_batch
 
     names = sorted(scenarios if scenarios is not None else SCENARIOS)
     rows: list[dict] = []
-    notes = ["walls are single-process wall-clock on the bench host; "
-             "cold includes the jit trace + compile, amortized over all "
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    notes = [f"walls are single-process wall-clock with the engine on "
+             f"{dev.platform} ({dev.device_kind}, {device['count']} "
+             "device(s)); cold includes the jit trace + compile (or its "
+             "persistent-cache load), amortized over all "
              f"{seeds} lanes by the shared padded batch shape"]
     for name in names:
         cells = [build_scenario(name, seed=s, lint=False)
@@ -202,7 +211,7 @@ def run_batched_bench(seeds: int, scenarios=None, smoke: bool = False) -> dict:
               f"warm {warm:6.2f}s  cold {cold:6.2f}s  "
               f"({row['speedup_warm']:.2f}x warm)", flush=True)
     out = {"engine": "repro.core.simjax", "policy": "fifo",
-           "seeds": seeds, "rows": rows, "notes": notes}
+           "device": device, "seeds": seeds, "rows": rows, "notes": notes}
     headline = next((r for r in rows if r["scenario"] == "pipe_serve"), None)
     if headline is not None:
         out["headline_scenario"] = "pipe_serve"
@@ -412,6 +421,9 @@ def main() -> None:
                     else f"BENCH_sim_core_{args.topology}.json")
 
     if args.batched:
+        from repro.core.simjax import place_compile_cache
+
+        place_compile_cache()
         seeds = 3 if args.smoke and args.batched_seeds == 20 \
             else args.batched_seeds
         scen = ("pipe_serve", "mixed") if args.smoke else None

@@ -41,16 +41,20 @@ Worked example — two seeds of a one-job scenario as one batch::
     >>> [r.jct["j0"] for r in res]      # unit caps: size / 1.0 seconds
     [10.0, 30.0]
 
-The wall-clock win over sequential numpy runs for the 20-seed fifo
-lanes (≥5x on pipe_serve, the paper's headline scenario) is recorded
-in ``BENCH_sim_core.json`` by ``benchmarks/perf_sim_core.py
---batched``, per scenario and with cold (compile-inclusive) numbers —
-batching also amortizes the jit trace: 20 lanes share one program.
+The host-CPU wall-clock win over sequential numpy runs for the 20-seed
+fifo lanes (≥5x on pipe_serve, the paper's headline scenario, on the
+CPU backend) is recorded in ``BENCH_sim_core.json`` by
+``benchmarks/perf_sim_core.py --batched``, per scenario and with cold
+(compile-inclusive) numbers — batching also amortizes the jit trace:
+20 lanes share one program.  It is not a TPU number; ``chip_smoke.py``
+runs the engine on the chip.
 """
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
+from pathlib import Path
 from typing import NamedTuple, Sequence
 
 import numpy as np
@@ -73,6 +77,7 @@ __all__ = [
     "LaneResult",
     "PackedInstance",
     "pack_instance",
+    "place_compile_cache",
     "run_fifo_batch",
     "trace_count",
 ]
@@ -259,8 +264,17 @@ def _pad_lists(lists: list[list[int]], width: int, fill: int) -> np.ndarray:
 
 def _seg_sum(vals: jnp.ndarray, bounds: jnp.ndarray) -> jnp.ndarray:
     """Sum ``vals`` ([B, M]) over the static segments described by
-    ``bounds`` ([B, D+1]) — cumsum + two gathers, no scatter."""
-    cs = jnp.pad(jnp.cumsum(vals, axis=1), ((0, 0), (1, 0)))
+    ``bounds`` ([B, D+1]) — prefix sum + two gathers, no scatter.
+
+    Float prefixes are an explicit ``associative_scan``: ``jnp.cumsum``
+    lowers to a length-M ``reduce_window``, which the TPU compiler takes
+    minutes to build in emulated float64 (integer windows compile in
+    well under a second there, so counts keep ``cumsum``)."""
+    if jnp.issubdtype(vals.dtype, jnp.floating):
+        cs = lax.associative_scan(jnp.add, vals, axis=1)
+    else:
+        cs = jnp.cumsum(vals, axis=1)
+    cs = jnp.pad(cs, ((0, 0), (1, 0)))
     bi = jnp.arange(vals.shape[0])[:, None]
     return cs[bi, bounds[:, 1:]] - cs[bi, bounds[:, :-1]]
 
@@ -464,7 +478,7 @@ def _kick(pk: _Batch, s: _State) -> _State:
     # scan whose body is elementwise on [B, links].
     w = jnp.where(live, s.flow_rem, 0.0)
     w_fl = jnp.repeat(w, L, axis=1)[bi, pk.jl_perm]
-    # XLA's cumsum is a reassociated tree scan, so an *empty* segment's
+    # The prefix sum is a reassociated tree scan, so an *empty* segment's
     # prefix difference can leave ±ulp-of-prefix residue instead of an
     # exact 0.0 — and a phantom "used" link on an exhausted residual
     # would wrongly refuse the whole MADD.  An integer count of live
@@ -581,9 +595,25 @@ def _multi_step(pk: _Batch, s: _State, n: int) -> _State:
     return lax.fori_loop(0, n, lambda _, st: _step(pk, st), s)
 
 
-_step_jit = jax.jit(_step)
 _multi_step_jit = jax.jit(_multi_step, static_argnums=2)
 _settle_jit = jax.jit(_settle)
+
+
+def place_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Entry points that run the engine call this before their first
+    compile; importing the module sets nothing.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and
+    wins.  Otherwise the cache is ``.jax_cache/`` at the root of this
+    checkout: a fixed path, because the directory is part of what a
+    later run must find."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def trace_count() -> int:

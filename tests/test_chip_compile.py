@@ -1,0 +1,76 @@
+"""The lockstep engine compiles for a TPU v5e chip (DESIGN.md §17).
+
+Nothing here runs on a chip: each test compiles ahead of time for a
+*described* ``v5e:2x2`` topology with the TPU compiler that ships with
+JAX, at the padded 20-lane shapes of ``pipe_serve`` (small-flow lanes)
+and ``dense_dp`` (large-flow lanes).  A change the chip's compiler
+refuses, or one that brings back a float64 ``reduce-window`` (what
+``jnp.cumsum`` lowers to, and which took the step program minutes to
+compile in emulated float64), fails here in seconds instead of on the
+chip.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library.
+"""
+
+from __future__ import annotations
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from repro.appdag.mixer import build_scenario  # noqa: E402
+from repro.core import simjax  # noqa: E402
+
+LANES = 20
+STEPS = 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A chip compile is written to the persistent cache but cannot be
+    # read back without a chip; keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(scenario: str, sharding):
+    lanes = [simjax.pack_instance(*build_scenario(scenario, seed=s,
+                                                  lint=False))
+             for s in range(LANES)]
+    pk = simjax._pack_batch(lanes)
+    st = jax.eval_shape(simjax._init_state, pk)
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        (pk, st))
+
+
+def _f64_reduce_windows(lowered) -> list[str]:
+    return [line for line in lowered.as_text(dialect="hlo").splitlines()
+            if "reduce-window(" in line
+            and "f64[" in line.split("reduce-window(")[0]]
+
+
+@pytest.mark.parametrize("scenario", ["pipe_serve", "dense_dp"])
+def test_step_window_compiles_for_v5e(one_chip, scenario):
+    pk, st = _shapes(scenario, one_chip)
+    settle = jax.jit(simjax._settle).lower(pk, st)
+    step = jax.jit(simjax._multi_step, static_argnums=2).lower(pk, st, STEPS)
+    assert _f64_reduce_windows(settle) == []
+    assert _f64_reduce_windows(step) == []
+    settle.compile()
+    step.compile()
