@@ -247,6 +247,10 @@ class _State(NamedTuple):
     done: jnp.ndarray           # [B] bool
     deadlock: jnp.ndarray       # [B] bool
     events: jnp.ndarray         # [B] i8
+    # Loop iterations run while the lane was unfinished (int32: 64-bit
+    # integers are emulated on the TPU).
+    waves: jnp.ndarray          # [B] i4  backfill wave iterations
+    cascades: jnp.ndarray       # [B] i4  settle cascade iterations
 
 
 def _bounds(ids: np.ndarray, n_segs: int) -> np.ndarray:
@@ -392,15 +396,19 @@ def _init_state(pk: _Batch) -> _State:
         done=jnp.zeros(B, dtype=bool),
         deadlock=jnp.zeros(B, dtype=bool),
         events=jnp.zeros(B, dtype=jnp.int64),
+        waves=jnp.zeros(B, dtype=jnp.int32),
+        cascades=jnp.zeros(B, dtype=jnp.int32),
     )
 
 
 # ------------------------------------------------------------------- settle
+@jax.named_scope("simjax.settle")
 def _settle(pk: _Batch, s: _State) -> _State:
     """Commit everything instantaneous at the current lane times:
     admissions, flow/metaflow/task completions, the DAG activation
     cascade (breadth-first waves to a fixpoint), job retirement, and
-    lane-done flags.  Idempotent — running it twice changes nothing."""
+    lane-done flags.  Idempotent on the simulated state — running it
+    twice changes nothing but the ``cascades`` counter."""
     B = s.t.shape[0]
     bi = jnp.arange(B)[:, None]
 
@@ -418,7 +426,7 @@ def _settle(pk: _Batch, s: _State) -> _State:
     adm_node = admitted[bi, pk.node_job]
 
     def cascade(carry):
-        node_state, pend, act_seq, act_ctr, last_flow, _ = carry
+        node_state, pend, act_seq, act_ctr, last_flow, n, _ = carry
         new_done = (node_state == 1) & jnp.where(pk.node_is_mf,
                                                  flows_left == 0,
                                                  s.task_rem <= EPS)
@@ -436,12 +444,12 @@ def _settle(pk: _Batch, s: _State) -> _State:
         act_seq = jnp.where(act, act_ctr[:, None] + rank - 1, act_seq)
         act_ctr = act_ctr + rank[:, -1]
         changed = (new_done | act).any()
-        return node_state, pend, act_seq, act_ctr, last_flow, changed
+        return node_state, pend, act_seq, act_ctr, last_flow, n + 1, changed
 
     carry = (s.node_state, s.pend, s.act_seq, s.act_ctr, last_flow,
-             jnp.array(True))
+             jnp.int32(0), jnp.array(True))
     carry = lax.while_loop(lambda c: c[-1], cascade, carry)
-    node_state, pend, act_seq, act_ctr, last_flow, _ = carry
+    node_state, pend, act_seq, act_ctr, last_flow, n_casc, _ = carry
 
     unfin = _seg_sum(((node_state != 2) & pk.node_valid).astype(jnp.int32),
                      pk.jn_bounds)
@@ -453,7 +461,8 @@ def _settle(pk: _Batch, s: _State) -> _State:
     return s._replace(admitted=admitted, node_state=node_state, pend=pend,
                       act_seq=act_seq, act_ctr=act_ctr, flow_done=flow_done,
                       job_done=job_done, job_finish=job_finish,
-                      last_flow=last_flow, done=done)
+                      last_flow=last_flow, done=done,
+                      cascades=s.cascades + jnp.where(s.done, 0, n_casc))
 
 
 # --------------------------------------------------------------------- kick
@@ -472,110 +481,114 @@ def _kick(pk: _Batch, s: _State) -> _State:
     bi = jnp.arange(B)[:, None]
     links_flat = pk.flow_links.reshape(B, F * L)
 
-    live = (s.node_state[bi, pk.flow_node] == 1) & (s.flow_rem > EPS)
+    with jax.named_scope("simjax.madd"):
+        # --- MADD walk: all (job, link) demands in one prefix pass, then a
+        # scan whose body is elementwise on [B, links].
+        live = (s.node_state[bi, pk.flow_node] == 1) & (s.flow_rem > EPS)
+        w = jnp.where(live, s.flow_rem, 0.0)
+        w_fl = jnp.repeat(w, L, axis=1)[bi, pk.jl_perm]
+        # The prefix sum is a reassociated tree scan, so an *empty* segment's
+        # prefix difference can leave ±ulp-of-prefix residue instead of an
+        # exact 0.0 — and a phantom "used" link on an exhausted residual
+        # would wrongly refuse the whole MADD.  An integer count of live
+        # contributors is exact; it gates which segments carry demand.
+        cnt = _seg_sum((w_fl > 0.0).astype(jnp.int32), pk.jl_bounds)
+        dem_all = jnp.where(cnt > 0, _seg_sum(w_fl, pk.jl_bounds),
+                            0.0).reshape(B, J1, K1)
 
-    # --- MADD walk: all (job, link) demands in one prefix pass, then a
-    # scan whose body is elementwise on [B, links].
-    w = jnp.where(live, s.flow_rem, 0.0)
-    w_fl = jnp.repeat(w, L, axis=1)[bi, pk.jl_perm]
-    # The prefix sum is a reassociated tree scan, so an *empty* segment's
-    # prefix difference can leave ±ulp-of-prefix residue instead of an
-    # exact 0.0 — and a phantom "used" link on an exhausted residual
-    # would wrongly refuse the whole MADD.  An integer count of live
-    # contributors is exact; it gates which segments carry demand.
-    cnt = _seg_sum((w_fl > 0.0).astype(jnp.int32), pk.jl_bounds)
-    dem_all = jnp.where(cnt > 0, _seg_sum(w_fl, pk.jl_bounds),
-                        0.0).reshape(B, J1, K1)
+        def madd(carry, dem):
+            res, gamma_ok = carry                  # dem: [B, K1] for this job
+            used = dem > 0.0
+            blocked = (used & (res <= EPS)).any(axis=1)
+            gamma = jnp.where(used & (res > EPS), dem / res, 0.0).max(axis=1)
+            ok = ~blocked & (gamma > EPS)
+            safe = jnp.where(ok, gamma, 1.0)
+            res = jnp.where(ok[:, None],
+                            jnp.clip(res - dem / safe[:, None], 0.0, None), res)
+            return (res, gamma_ok), (ok, safe)
 
-    def madd(carry, dem):
-        res, gamma_ok = carry                  # dem: [B, K1] for this job
-        used = dem > 0.0
-        blocked = (used & (res <= EPS)).any(axis=1)
-        gamma = jnp.where(used & (res > EPS), dem / res, 0.0).max(axis=1)
-        ok = ~blocked & (gamma > EPS)
-        safe = jnp.where(ok, gamma, 1.0)
-        res = jnp.where(ok[:, None],
-                        jnp.clip(res - dem / safe[:, None], 0.0, None), res)
-        return (res, gamma_ok), (ok, safe)
+        (res, _), (ok_j, gamma_j) = lax.scan(
+            madd, (pk.link_cap, None), jnp.moveaxis(dem_all[:, :J1 - 1], 0, 1))
+        ok_j = jnp.concatenate([jnp.moveaxis(ok_j, 0, 1),
+                                jnp.zeros((B, 1), dtype=bool)], axis=1)
+        gamma_j = jnp.concatenate([jnp.moveaxis(gamma_j, 0, 1),
+                                   jnp.ones((B, 1))], axis=1)
+        rates = jnp.where(live & ok_j[bi, pk.flow_job],
+                          s.flow_rem / gamma_j[bi, pk.flow_job], 0.0)
 
-    (res, _), (ok_j, gamma_j) = lax.scan(
-        madd, (pk.link_cap, None), jnp.moveaxis(dem_all[:, :J1 - 1], 0, 1))
-    ok_j = jnp.concatenate([jnp.moveaxis(ok_j, 0, 1),
-                            jnp.zeros((B, 1), dtype=bool)], axis=1)
-    gamma_j = jnp.concatenate([jnp.moveaxis(gamma_j, 0, 1),
-                               jnp.ones((B, 1))], axis=1)
-    rates = jnp.where(live & ok_j[bi, pk.flow_job],
-                      s.flow_rem / gamma_j[bi, pk.flow_job], 0.0)
+    with jax.named_scope("simjax.backfill"):
+        # --- backfill: priority key = (job, metaflow activation order, flow
+        # position) — the numpy walk's concatenation order.  Flows execute
+        # in priority *waves*: a flow runs once no pending higher-priority
+        # flow shares any of its links, which applies the per-link
+        # subtractions in exactly the sequential sweep's order.  The numpy
+        # core's first-live-flow-per-route optimization needs no analogue
+        # here: a grant zeroes the path's smallest residual, so same-route
+        # followers are retired by the capacity filter below, exactly.
+        seq = jnp.minimum(s.act_seq[bi, pk.flow_node], N1 + 1)
+        key = ((pk.flow_job.astype(jnp.int64) * (N1 + 2) + seq) * (F + 1)
+               + pk.flow_pos)
+        keyed = jnp.where(live, key, _BIG)
 
-    # --- backfill: priority key = (job, metaflow activation order, flow
-    # position) — the numpy walk's concatenation order.  Flows execute
-    # in priority *waves*: a flow runs once no pending higher-priority
-    # flow shares any of its links, which applies the per-link
-    # subtractions in exactly the sequential sweep's order.  The numpy
-    # core's first-live-flow-per-route optimization needs no analogue
-    # here: a grant zeroes the path's smallest residual, so same-route
-    # followers are retired by the capacity filter below, exactly.
-    seq = jnp.minimum(s.act_seq[bi, pk.flow_node], N1 + 1)
-    key = ((pk.flow_job.astype(jnp.int64) * (N1 + 2) + seq) * (F + 1)
-           + pk.flow_pos)
-    keyed = jnp.where(live, key, _BIG)
+        def wave(carry):
+            res, rates, pending, n, _ = carry
+            # Residuals only shrink during the sweep, so a flow whose path
+            # minimum is already ≤ EPS can never receive a grant at its
+            # turn — retiring it now is exact and collapses the priority
+            # chains to the few flows with actual capacity.
+            h_row = res[bi, links_flat].reshape(B, F, L).min(axis=2)
+            pending = pending & (h_row > EPS)
+            key_p = jnp.where(pending, keyed, _BIG)
+            key_fl = jnp.concatenate([jnp.repeat(key_p, L, axis=1),
+                                      jnp.full((B, 1), _BIG)], axis=1)
+            best = key_fl[bi[:, :, None], pk.link_pairs].min(axis=2)  # [B, K1]
+            # A flow is at its turn iff it is the best (minimum-key) pending
+            # flow on EVERY link it crosses.  best ≤ key on each of its real
+            # links (its own key participates in those minima), so the test
+            # is min-over-links == key; the dummy link is pinned to the
+            # sentinel so padded path positions cannot veto a turn.
+            best = jnp.where(jnp.arange(K1) == K1 - 1, _BIG, best)
+            at_turn = pending & (best[bi, links_flat].reshape(B, F, L)
+                                 .min(axis=2) == keyed)
+            h = jnp.where(at_turn, h_row, 0.0)
+            rates = rates + h
+            h_fl = jnp.concatenate([jnp.repeat(h, L, axis=1),
+                                    jnp.zeros((B, 1))], axis=1)
+            sub = h_fl[bi[:, :, None], pk.link_pairs].sum(axis=2)
+            res = res - jnp.where(jnp.arange(K1) == K1 - 1, 0.0, sub)
+            pending = pending & ~at_turn
+            return res, rates, pending, n + 1, pending.any()
 
-    def wave(carry):
-        res, rates, pending, _ = carry
-        # Residuals only shrink during the sweep, so a flow whose path
-        # minimum is already ≤ EPS can never receive a grant at its
-        # turn — retiring it now is exact and collapses the priority
-        # chains to the few flows with actual capacity.
-        h_row = res[bi, links_flat].reshape(B, F, L).min(axis=2)
-        pending = pending & (h_row > EPS)
-        key_p = jnp.where(pending, keyed, _BIG)
-        key_fl = jnp.concatenate([jnp.repeat(key_p, L, axis=1),
-                                  jnp.full((B, 1), _BIG)], axis=1)
-        best = key_fl[bi[:, :, None], pk.link_pairs].min(axis=2)  # [B, K1]
-        # A flow is at its turn iff it is the best (minimum-key) pending
-        # flow on EVERY link it crosses.  best ≤ key on each of its real
-        # links (its own key participates in those minima), so the test
-        # is min-over-links == key; the dummy link is pinned to the
-        # sentinel so padded path positions cannot veto a turn.
-        best = jnp.where(jnp.arange(K1) == K1 - 1, _BIG, best)
-        at_turn = pending & (best[bi, links_flat].reshape(B, F, L)
-                             .min(axis=2) == keyed)
-        h = jnp.where(at_turn, h_row, 0.0)
-        rates = rates + h
-        h_fl = jnp.concatenate([jnp.repeat(h, L, axis=1),
-                                jnp.zeros((B, 1))], axis=1)
-        sub = h_fl[bi[:, :, None], pk.link_pairs].sum(axis=2)
-        res = res - jnp.where(jnp.arange(K1) == K1 - 1, 0.0, sub)
-        pending = pending & ~at_turn
-        return res, rates, pending, pending.any()
+        carry = (res, rates, live, jnp.int32(0), live.any())
+        res, rates, _, n_waves, _ = lax.while_loop(lambda c: c[-1], wave,
+                                                   carry)
 
-    carry = (res, rates, live, live.any())
-    res, rates, _, _ = lax.while_loop(lambda c: c[-1], wave, carry)
+    with jax.named_scope("simjax.horizon"):
+        # --- event horizon
+        flowing = (rates > EPS) & (s.flow_rem > EPS)
+        dt = jnp.where(flowing, s.flow_rem / jnp.where(flowing, rates, 1.0),
+                       jnp.inf).min(axis=1)
+        task_running = (s.node_state == 1) & ~pk.node_is_mf & pk.node_valid
+        dt = jnp.minimum(dt, jnp.where(task_running, s.task_rem, jnp.inf)
+                         .min(axis=1) / pk.speed)
+        waiting = pk.job_valid & ~s.admitted
+        dt = jnp.minimum(dt, jnp.where(waiting, pk.arrival, jnp.inf)
+                         .min(axis=1) - s.t)
+        dead = ~s.done & jnp.isinf(dt)
+        dt = jnp.where(s.done | dead, 0.0, jnp.maximum(dt, 0.0))
 
-    # --- event horizon
-    flowing = (rates > EPS) & (s.flow_rem > EPS)
-    dt = jnp.where(flowing, s.flow_rem / jnp.where(flowing, rates, 1.0),
-                   jnp.inf).min(axis=1)
-    task_running = (s.node_state == 1) & ~pk.node_is_mf & pk.node_valid
-    dt = jnp.minimum(dt, jnp.where(task_running, s.task_rem, jnp.inf)
-                     .min(axis=1) / pk.speed)
-    waiting = pk.job_valid & ~s.admitted
-    dt = jnp.minimum(dt, jnp.where(waiting, pk.arrival, jnp.inf)
-                     .min(axis=1) - s.t)
-    dead = ~s.done & jnp.isinf(dt)
-    dt = jnp.where(s.done | dead, 0.0, jnp.maximum(dt, 0.0))
-
-    # --- fluid advance
-    flow_rem = jnp.where(
-        flowing, jnp.clip(s.flow_rem - rates * dt[:, None], 0.0, None),
-        s.flow_rem)
-    task_rem = jnp.where(
-        task_running,
-        jnp.maximum(s.task_rem - pk.speed[:, None] * dt[:, None], 0.0),
-        s.task_rem)
-    return s._replace(t=s.t + dt, flow_rem=flow_rem, task_rem=task_rem,
-                      deadlock=s.deadlock | dead,
-                      events=s.events + (~s.done).astype(jnp.int64))
+        # --- fluid advance
+        flow_rem = jnp.where(
+            flowing, jnp.clip(s.flow_rem - rates * dt[:, None], 0.0, None),
+            s.flow_rem)
+        task_rem = jnp.where(
+            task_running,
+            jnp.maximum(s.task_rem - pk.speed[:, None] * dt[:, None], 0.0),
+            s.task_rem)
+        return s._replace(t=s.t + dt, flow_rem=flow_rem, task_rem=task_rem,
+                          deadlock=s.deadlock | dead,
+                          events=s.events + (~s.done).astype(jnp.int64),
+                          waves=s.waves + jnp.where(s.done, 0, n_waves))
 
 
 _TRACES = 0
@@ -627,12 +640,22 @@ def trace_count() -> int:
 @dataclass(frozen=True)
 class LaneResult:
     """Per-lane outcome, keyed like ``SimResult``: per-job JCT/CCT by
-    job name, plus the lane makespan and lockstep event count."""
+    job name, plus the lane makespan and lockstep event count.
+
+    The counters say what the engine did for the lane: backfill wave
+    and settle cascade iterations run while the lane was unfinished
+    (the batch's count is the maximum over its lanes), and, the same for
+    every lane of a batch, the lockstep steps the device executed and
+    the host syncs (reads of the done/deadlock flags) it took."""
 
     jct: dict[str, float]
     cct: dict[str, float]
     makespan: float
     events: int
+    wave_iters: int = 0
+    cascade_iters: int = 0
+    batch_steps: int = 0
+    batch_syncs: int = 0
 
 
 def run_fifo_batch(lanes: Sequence[PackedInstance], *,
@@ -643,37 +666,63 @@ def run_fifo_batch(lanes: Sequence[PackedInstance], *,
     lockstep events run per host round-trip — finished lanes are masked
     no-ops, so overshooting a fast lane's final event is harmless.
     Raises on deadlock (mirroring the numpy core) and on ``max_events``
-    (livelock guard)."""
+    (livelock guard).
+
+    Under ``jax.profiler`` the call shows as host spans, on the device
+    trace's clock: ``simjax.pack_batch`` (padding and the copies to the
+    device), ``simjax.init`` (first settle), then per window
+    ``simjax.sync`` (the done/deadlock read) and ``simjax.dispatch``
+    (the ``steps_per_sync``-step program), and ``simjax.readback``.  Its
+    device ops carry the named scopes ``simjax.settle``, ``simjax.madd``,
+    ``simjax.backfill`` and ``simjax.horizon`` in their op names.  With
+    the profiler off the spans cost a no-op each.  Counters: see
+    :class:`LaneResult`; ``wave_iters / batch_steps`` is backfill waves
+    per step, ``max(events) / batch_steps`` the share of executed steps
+    some lane needed.  DESIGN.md §17 says how to read them."""
     if not lanes:
         return []
-    pk = _pack_batch(lanes)
-    s = _settle_jit(pk, _init_state(pk))
-    steps = 0
+    with jax.profiler.TraceAnnotation("simjax.pack_batch"):
+        pk = _pack_batch(lanes)
+    with jax.profiler.TraceAnnotation("simjax.init"):
+        s = _settle_jit(pk, _init_state(pk))
+    steps = syncs = 0
     while True:
-        halted = np.asarray(s.done | s.deadlock)
+        with jax.profiler.TraceAnnotation("simjax.sync"):
+            halted = np.asarray(s.done | s.deadlock)
+        syncs += 1
         if halted.all():
             break
         if steps > max_events:
             raise RuntimeError(
                 "batched simulator exceeded max_events — livelock?")
-        s = _multi_step_jit(pk, s, steps_per_sync)
+        with jax.profiler.TraceAnnotation("simjax.dispatch"):
+            s = _multi_step_jit(pk, s, steps_per_sync)
         steps += steps_per_sync
-    if bool(np.asarray(s.deadlock).any()):
-        bad = [i for i, d in enumerate(np.asarray(s.deadlock).tolist()) if d]
-        raise RuntimeError(f"deadlock: no progress possible in lanes {bad}")
+    with jax.profiler.TraceAnnotation("simjax.readback"):
+        if bool(np.asarray(s.deadlock).any()):
+            bad = [i for i, d in enumerate(np.asarray(s.deadlock).tolist())
+                   if d]
+            raise RuntimeError(
+                f"deadlock: no progress possible in lanes {bad}")
 
-    t = np.asarray(s.t)
-    jf = np.asarray(s.job_finish)
-    lf = np.asarray(s.last_flow)
-    ev = np.asarray(s.events)
-    return [
-        LaneResult(
-            jct={n: float(jf[b, i] - p.arrival[i])
-                 for i, n in enumerate(p.job_names)},
-            cct={n: float(lf[b, i] - p.arrival[i])
-                 for i, n in enumerate(p.job_names)},
-            makespan=float(t[b]),
-            events=int(ev[b]),
-        )
-        for b, p in enumerate(lanes)
-    ]
+        t = np.asarray(s.t)
+        jf = np.asarray(s.job_finish)
+        lf = np.asarray(s.last_flow)
+        ev = np.asarray(s.events)
+        wv = np.asarray(s.waves)
+        cs = np.asarray(s.cascades)
+        return [
+            LaneResult(
+                jct={n: float(jf[b, i] - p.arrival[i])
+                     for i, n in enumerate(p.job_names)},
+                cct={n: float(lf[b, i] - p.arrival[i])
+                     for i, n in enumerate(p.job_names)},
+                makespan=float(t[b]),
+                events=int(ev[b]),
+                wave_iters=int(wv[b]),
+                cascade_iters=int(cs[b]),
+                batch_steps=steps,
+                batch_syncs=syncs,
+            )
+            for b, p in enumerate(lanes)
+        ]

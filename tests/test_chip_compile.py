@@ -7,7 +7,8 @@ and ``dense_dp`` (large-flow lanes).  A change the chip's compiler
 refuses, or one that brings back a float64 ``reduce-window`` (what
 ``jnp.cumsum`` lowers to, and which took the step program minutes to
 compile in emulated float64), fails here in seconds instead of on the
-chip.
+chip.  The compiled programs must also keep the engine's named scopes in
+their op names and its loop counters in 32 bits.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
@@ -26,6 +27,7 @@ from repro.core import simjax  # noqa: E402
 
 LANES = 20
 STEPS = 16
+PHASES = ("simjax.settle", "simjax.madd", "simjax.backfill", "simjax.horizon")
 
 
 @pytest.fixture(scope="module")
@@ -65,12 +67,20 @@ def _f64_reduce_windows(lowered) -> list[str]:
             and "f64[" in line.split("reduce-window(")[0]]
 
 
+def _scopes(compiled_text: str) -> set[str]:
+    return {p for p in PHASES if f"/{p}/" in compiled_text}
+
+
 @pytest.mark.parametrize("scenario", ["pipe_serve", "dense_dp"])
 def test_step_window_compiles_for_v5e(one_chip, scenario):
     pk, st = _shapes(scenario, one_chip)
+    # The loop counters stay 32-bit: 64-bit integers are emulated there.
+    assert st.waves.dtype == st.cascades.dtype == jax.numpy.int32
     settle = jax.jit(simjax._settle).lower(pk, st)
     step = jax.jit(simjax._multi_step, static_argnums=2).lower(pk, st, STEPS)
     assert _f64_reduce_windows(settle) == []
     assert _f64_reduce_windows(step) == []
-    settle.compile()
-    step.compile()
+    # The named scopes survive the chip's compiler, in the op names a
+    # profiler trace of the program carries.
+    assert _scopes(settle.compile().as_text()) == {"simjax.settle"}
+    assert _scopes(step.compile().as_text()) == set(PHASES)

@@ -169,6 +169,89 @@ class TestRecompilation:
         assert trace_count() == traced + 1
 
 
+class TestCounters:
+    """The engine's loop counters on batches small enough to count by
+    hand: a wave per backfill round, a cascade iteration per settle
+    round (the last one finding nothing to do), each counted for a lane
+    while it is unfinished."""
+
+    @staticmethod
+    def _shared_link():
+        # j0 saturates ports 0 -> 1, so j1's MADD is refused and its two
+        # flows into port 3 backfill one after the other: two waves in
+        # step 1, one in step 2 (j1's last flows, MADD leaves nothing).
+        j0 = JobDAG("j0")
+        j0.add_metaflow("m0", [(0, 1, 1.0)])
+        j1 = JobDAG("j1")
+        j1.add_metaflow("m0", [(0, 1, 1.0), (2, 3, 1.0), (4, 3, 1.0)])
+        return [j0, j1]
+
+    @staticmethod
+    def _chain(depth: int):
+        # ``depth`` flowless metaflows in a chain, then one flow: the
+        # first settle activates and retires one link of the chain per
+        # iteration.
+        job = JobDAG("j0")
+        deps: list[str] = []
+        for i in range(depth):
+            job.add_metaflow(f"m{i}", [], deps=deps)
+            deps = [f"m{i}"]
+        job.add_metaflow("last", [(0, 1, 1.0)], deps=deps)
+        return [job]
+
+    def _check_oracle(self, lane: LaneResult, jobs, n_ports: int):
+        ref = simulate(jobs, make_scheduler("fifo"), n_ports=n_ports)
+        assert _max_diff(lane, ref) < TOL
+
+    def test_two_flows_sharing_one_link(self):
+        (res,) = run_fifo_batch([pack_instance(Fabric(n_ports=5),
+                                               self._shared_link())])
+        self._check_oracle(res, self._shared_link(), 5)
+        assert res.jct == {"j0": 1.0, "j1": 2.0}
+        assert res.events == 2
+        assert res.wave_iters == 2 + 1
+        # First settle (activate both roots, then nothing) and one
+        # settle per step (retire a metaflow or none, then nothing).
+        assert res.cascade_iters == 2 + 2 + 2
+        # One window of 16 steps, read before and after it.
+        assert (res.batch_steps, res.batch_syncs) == (16, 2)
+
+    @pytest.mark.parametrize("depth", [1, 3])
+    def test_chain_cascade_depth(self, depth):
+        (res,) = run_fifo_batch([pack_instance(Fabric(n_ports=2),
+                                               self._chain(depth))])
+        self._check_oracle(res, self._chain(depth), 2)
+        assert res.events == 1 and res.wave_iters == 1
+        # First settle: the root, each chain link's retirement with its
+        # child's activation, then nothing; the step's settle: 2.
+        assert res.cascade_iters == (depth + 2) + 2
+
+    def test_batch_counts_while_unfinished(self):
+        """Loops run batch-wide: a lane counts every iteration while it
+        is unfinished, including rounds only another lane needed."""
+        steps = 4
+        res = run_fifo_batch(
+            [pack_instance(Fabric(n_ports=5), self._shared_link()),
+             pack_instance(Fabric(n_ports=2), self._chain(3))],
+            steps_per_sync=steps)
+        self._check_oracle(res[0], self._shared_link(), 5)
+        self._check_oracle(res[1], self._chain(3), 2)
+        # Lane 1 finishes in step 1, so it sees step 1's two waves only.
+        assert [r.wave_iters for r in res] == [3, 2]
+        # The first settle runs the chain's 5 iterations for both lanes.
+        assert [r.cascade_iters for r in res] == [5 + 2 + 2, 5 + 2]
+        assert {(r.batch_steps, r.batch_syncs) for r in res} == {(steps, 2)}
+
+    def test_windows_overshoot(self):
+        """Steps run in whole windows: 16 steps for 2 needed, and a sync
+        before each window and after the last."""
+        lanes = [pack_instance(Fabric(n_ports=5), self._shared_link())]
+        for per_sync, steps, syncs in ((1, 2, 3), (16, 16, 2)):
+            (res,) = run_fifo_batch(lanes, steps_per_sync=per_sync)
+            assert (res.batch_steps, res.batch_syncs) == (steps, syncs)
+            assert res.wave_iters == 3 and res.cascade_iters == 6
+
+
 class TestRunnerIntegration:
     def test_run_cells_batched_order_and_fallback(self):
         from repro.experiments import Cell, run_cell, run_cells_batched
