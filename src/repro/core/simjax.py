@@ -19,6 +19,9 @@ run as priority *waves*: any group whose contended links are free of
 higher-priority pending groups executes now, which reproduces the
 sequential order link-by-link (flows sharing a link always execute in
 key order across waves) while finishing in a handful of iterations.
+Inside a backfill wave no index is read: every flow↔link lookup is a
+masked reduction over a static flow × link mask, because a TPU runs a
+gather one element at a time.
 
 The numpy core stays the oracle (the ``simref.ReferenceSimulator``
 pattern): results agree per-lane on JCT/CCT within float tolerance —
@@ -199,9 +202,7 @@ class _Batch(NamedTuple):
     machinery for scatter-free reductions.  Dummy slots: job ``J``
     (arrival=inf, invalid), node ``N`` (pend huge, never activates),
     link ``K`` (cap=inf, absorbs padded path positions), route ``R``
-    (collects padded flows, which are never live), flow ``F`` /
-    flat-position ``F*L`` (gather targets resolving to neutral
-    elements)."""
+    (collects padded flows, which are never live)."""
 
     arrival: jnp.ndarray        # [B, J+1] f8 (pad inf)
     job_valid: jnp.ndarray      # [B, J+1] bool
@@ -227,8 +228,8 @@ class _Batch(NamedTuple):
     # (job, link) demand segments over the flat (flow, leg) space
     jl_perm: jnp.ndarray        # [B, F*L] i4  sort by job*(K+1)+link
     jl_bounds: jnp.ndarray      # [B, (J+1)*(K+1)+1] i4
-    # per-link flat (flow, leg) positions (pad F*L), real links only
-    link_pairs: jnp.ndarray     # [B, K+1, ML] i4
+    # flow x link incidence: flow f crosses real link k (dummy K never)
+    link_mask: jnp.ndarray      # [B, F, K+1] bool
 
 
 class _State(NamedTuple):
@@ -257,13 +258,6 @@ def _bounds(ids: np.ndarray, n_segs: int) -> np.ndarray:
     """Segment bounds of a *sorted* id array: segment ``d`` occupies
     ``[out[d], out[d+1])``."""
     return np.searchsorted(ids, np.arange(n_segs + 1)).astype(np.int32)
-
-
-def _pad_lists(lists: list[list[int]], width: int, fill: int) -> np.ndarray:
-    out = np.full((len(lists), width), fill, dtype=np.int32)
-    for i, row in enumerate(lists):
-        out[i, :len(row)] = row
-    return out
 
 
 def _seg_sum(vals: jnp.ndarray, bounds: jnp.ndarray) -> jnp.ndarray:
@@ -355,24 +349,22 @@ def _pack_batch(lanes: Sequence[PackedInstance]) -> _Batch:
                                    axis=1)[b], (J + 1) * K1)
         for b in range(B)])
 
-    link_lists: list[list[int]] = []
-    for b, p in enumerate(lanes):
-        per_link: list[list[int]] = [[] for _ in range(K1)]
-        flat = links_flat[b]
-        for pos_i in range(p.flow_node.size * L):
-            lk = int(flat[pos_i])
-            if lk < K:                       # real links only
-                per_link[lk].append(pos_i)
-        link_lists.extend(per_link)
-    ml = max((len(x) for x in link_lists), default=0) or 1
-    link_pairs = _pad_lists(link_lists, ml, F * L).reshape(B, K1, ml)
+    # The mask counts a link once per flow, which stands for the route
+    # only if no route crosses a link twice.
+    legs = np.sort(flow_links, axis=2)
+    if ((legs[..., 1:] == legs[..., :-1]) & (legs[..., 1:] < K)).any():
+        raise ValueError("a route crosses one link twice")
+    real = flow_links < K
+    b_ix, f_ix, _ = np.nonzero(real)
+    link_mask = np.zeros((B, F, K1), dtype=bool)
+    link_mask[b_ix, f_ix, flow_links[real]] = True
 
     return _Batch(*map(jnp.asarray, (
         arrival, job_valid, node_job, node_is_mf, node_load, node_pend0,
         node_valid, edge_parent, flow_node, flow_job, flow_size,
         flow_links, flow_pathid, flow_pos, link_cap, speed,
         nf_bounds, ne_bounds, jn_bounds, jf_bounds,
-        jl_perm, jl_bounds, link_pairs)))
+        jl_perm, jl_bounds, link_mask)))
 
 
 def _init_state(pk: _Batch) -> _State:
@@ -479,7 +471,6 @@ def _kick(pk: _Batch, s: _State) -> _State:
     L = pk.flow_links.shape[2]
     K1 = pk.link_cap.shape[1]
     bi = jnp.arange(B)[:, None]
-    links_flat = pk.flow_links.reshape(B, F * L)
 
     with jax.named_scope("simjax.madd"):
         # --- MADD walk: all (job, link) demands in one prefix pass, then a
@@ -529,6 +520,11 @@ def _kick(pk: _Batch, s: _State) -> _State:
         key = ((pk.flow_job.astype(jnp.int64) * (N1 + 2) + seq) * (F + 1)
                + pk.flow_pos)
         keyed = jnp.where(live, key, _BIG)
+        # Every flow <-> link lookup of a wave is a masked reduction over
+        # the [B, F, K+1] incidence: no gathers in the loop body.  The
+        # dummy link is in no flow's row and no flow is in its column, so
+        # padded path legs and padded flows drop out of every reduction.
+        mask = pk.link_mask
 
         def wave(carry):
             res, rates, pending, n, _ = carry
@@ -536,26 +532,21 @@ def _kick(pk: _Batch, s: _State) -> _State:
             # minimum is already ≤ EPS can never receive a grant at its
             # turn — retiring it now is exact and collapses the priority
             # chains to the few flows with actual capacity.
-            h_row = res[bi, links_flat].reshape(B, F, L).min(axis=2)
+            h_row = jnp.where(mask, res[:, None, :], jnp.inf).min(axis=2)
             pending = pending & (h_row > EPS)
             key_p = jnp.where(pending, keyed, _BIG)
-            key_fl = jnp.concatenate([jnp.repeat(key_p, L, axis=1),
-                                      jnp.full((B, 1), _BIG)], axis=1)
-            best = key_fl[bi[:, :, None], pk.link_pairs].min(axis=2)  # [B, K1]
+            best = jnp.where(mask, key_p[:, :, None], _BIG).min(axis=1)
             # A flow is at its turn iff it is the best (minimum-key) pending
-            # flow on EVERY link it crosses.  best ≤ key on each of its real
-            # links (its own key participates in those minima), so the test
-            # is min-over-links == key; the dummy link is pinned to the
-            # sentinel so padded path positions cannot veto a turn.
-            best = jnp.where(jnp.arange(K1) == K1 - 1, _BIG, best)
-            at_turn = pending & (best[bi, links_flat].reshape(B, F, L)
+            # flow on EVERY link it crosses.  best ≤ key on each of its
+            # links (its own key participates in those minima), so the
+            # test is min-over-links == key.
+            at_turn = pending & (jnp.where(mask, best[:, None, :], _BIG)
                                  .min(axis=2) == keyed)
             h = jnp.where(at_turn, h_row, 0.0)
             rates = rates + h
-            h_fl = jnp.concatenate([jnp.repeat(h, L, axis=1),
-                                    jnp.zeros((B, 1))], axis=1)
-            sub = h_fl[bi[:, :, None], pk.link_pairs].sum(axis=2)
-            res = res - jnp.where(jnp.arange(K1) == K1 - 1, 0.0, sub)
+            # At-turn flows are link-disjoint (keys are unique), so each
+            # link's sum has at most one non-zero term: exact.
+            res = res - jnp.where(mask, h[:, :, None], 0.0).sum(axis=1)
             pending = pending & ~at_turn
             return res, rates, pending, n + 1, pending.any()
 

@@ -8,13 +8,17 @@ refuses, or one that brings back a float64 ``reduce-window`` (what
 ``jnp.cumsum`` lowers to, and which took the step program minutes to
 compile in emulated float64), fails here in seconds instead of on the
 chip.  The compiled programs must also keep the engine's named scopes in
-their op names and its loop counters in 32 bits.
+their op names and its loop counters in 32 bits, and the backfill wave
+loop must stay free of gathers: the TPU runs a gather one element at a
+time, about 10 ns each.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
 """
 
 from __future__ import annotations
+
+import re
 
 import pytest
 
@@ -71,16 +75,53 @@ def _scopes(compiled_text: str) -> set[str]:
     return {p for p in PHASES if f"/{p}/" in compiled_text}
 
 
+def _gathers_under(compiled_text: str, scope: str) -> list[str]:
+    """The compiled program's gather instructions whose op name lies
+    under ``scope``."""
+    return [line for line in compiled_text.splitlines()
+            if re.search(r"\sgather\(", line)
+            and re.search(rf'op_name="[^"]*{re.escape(scope)}/', line)]
+
+
+@pytest.fixture(scope="module")
+def programs(one_chip):
+    """Per scenario, compiled once for the module: the state's shapes,
+    the lowered settle and step-window programs, and the text of each
+    compiled program."""
+    built = {}
+
+    def get(scenario: str) -> dict:
+        if scenario not in built:
+            pk, st = _shapes(scenario, one_chip)
+            settle = jax.jit(simjax._settle).lower(pk, st)
+            step = jax.jit(simjax._multi_step,
+                           static_argnums=2).lower(pk, st, STEPS)
+            built[scenario] = {
+                "state": st, "settle": settle, "step": step,
+                "settle_text": settle.compile().as_text(),
+                "step_text": step.compile().as_text()}
+        return built[scenario]
+
+    return get
+
+
 @pytest.mark.parametrize("scenario", ["pipe_serve", "dense_dp"])
-def test_step_window_compiles_for_v5e(one_chip, scenario):
-    pk, st = _shapes(scenario, one_chip)
+def test_step_window_compiles_for_v5e(programs, scenario):
+    got = programs(scenario)
+    st = got["state"]
     # The loop counters stay 32-bit: 64-bit integers are emulated there.
     assert st.waves.dtype == st.cascades.dtype == jax.numpy.int32
-    settle = jax.jit(simjax._settle).lower(pk, st)
-    step = jax.jit(simjax._multi_step, static_argnums=2).lower(pk, st, STEPS)
-    assert _f64_reduce_windows(settle) == []
-    assert _f64_reduce_windows(step) == []
+    assert _f64_reduce_windows(got["settle"]) == []
+    assert _f64_reduce_windows(got["step"]) == []
     # The named scopes survive the chip's compiler, in the op names a
     # profiler trace of the program carries.
-    assert _scopes(settle.compile().as_text()) == {"simjax.settle"}
-    assert _scopes(step.compile().as_text()) == set(PHASES)
+    assert _scopes(got["settle_text"]) == {"simjax.settle"}
+    assert _scopes(got["step_text"]) == set(PHASES)
+
+
+@pytest.mark.parametrize("scenario", ["pipe_serve", "dense_dp"])
+def test_backfill_wave_body_has_no_gather(programs, scenario):
+    text = programs(scenario)["step_text"]
+    # The scope is in the program, so an empty list is not vacuous.
+    assert "simjax.backfill/while/body/" in text
+    assert _gathers_under(text, "simjax.backfill/while/body") == []

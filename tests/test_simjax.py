@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import random
 
+import numpy as np
 import pytest
 
 jax = pytest.importorskip(
@@ -25,8 +26,9 @@ jax = pytest.importorskip(
 
 from repro.appdag.mixer import SCENARIOS, build_scenario  # noqa: E402
 from repro.core import Fabric, JobDAG, make_scheduler, simulate  # noqa: E402
-from repro.core.simjax import (LaneResult, pack_instance,  # noqa: E402
-                               run_fifo_batch, trace_count)
+from repro.core.fabric import Topology, make_topology  # noqa: E402
+from repro.core.simjax import (LaneResult, _pack_batch,  # noqa: E402
+                               pack_instance, run_fifo_batch, trace_count)
 
 TOL = 1e-6
 N_SEEDS = 5
@@ -141,6 +143,115 @@ class TestPaddingMask:
 
         run()
 
+
+
+class TestLinkMask:
+    """The backfill's flow x link mask stands for the packed routes.  It
+    counts a link once per flow, so it matches a route only if the route
+    crosses no link twice."""
+
+    def test_mask_equals_link_lists(self):
+        # Big switch, 3:1 leaf-spine and fat tree lanes in one batch:
+        # link counts, path lengths and flow counts all differ.
+        built = [build_scenario(s, seed=i, quick=True, lint=False,
+                                topology=t)
+                 for i, (s, t) in enumerate((
+                     ("pipe_serve", None), ("mixed_oversub_3to1", None),
+                     ("fb_shuffle", "fat_tree"), ("dense_dp", None)))]
+        lanes = [pack_instance(f, j) for f, j in built]
+        assert len({p.n_links for p in lanes}) == len(lanes)
+        assert len({p.flow_links.shape[1] for p in lanes}) > 1
+        mask = np.asarray(_pack_batch(lanes).link_mask)
+        want = np.zeros_like(mask)
+        for b, p in enumerate(lanes):
+            for f, route in enumerate(p.flow_links):
+                for k in route:
+                    if k < p.n_links:
+                        want[b, f, k] = True
+        assert mask.shape[2] == max(p.n_links for p in lanes) + 1
+        assert (mask == want).all()
+        assert not mask[:, :, -1].any()          # the dummy link
+
+    @pytest.mark.parametrize("spec", ["big_switch", "leaf_spine_3to1",
+                                      "fat_tree"])
+    def test_routes_never_repeat_a_link(self, spec):
+        topo = make_topology(spec, 48)
+        for src in range(topo.n_ports):
+            for dst in range(topo.n_ports):
+                for route in topo.route_candidates(src, dst):
+                    assert len(set(route)) == len(route), (src, dst, route)
+
+    def test_engine_refuses_a_route_that_repeats_a_link(self):
+        class Hairpin(Topology):
+            kind = "hairpin"
+
+            def __init__(self):
+                super().__init__(2, np.ones(5), [f"l{i}" for i in range(5)])
+
+            def _route(self, src, dst):
+                return (src, 4, 4, 2 + dst)
+
+        job = JobDAG("j0")
+        job.add_metaflow("m0", [(0, 1, 1.0)])
+        lane = pack_instance(Fabric(topology=Hairpin()), [job])
+        with pytest.raises(ValueError, match="twice"):
+            run_fifo_batch([lane])
+
+
+class TestRecordedBatch:
+    """One fixed batch of registered scenarios on all three topologies
+    gives, lane by lane, exactly the loop counters, events, JCTs and CCTs
+    recorded from the engine while its backfill waves still gathered
+    through per-link lists of flow legs.  The masked reductions take the
+    same minima and make the same single grant per link, so every number
+    is bit-identical."""
+
+    LANES = (("pipe_serve", 0, None), ("dense_dp", 1, None),
+             ("moe_ep", 2, None), ("mixed_oversub_3to1", 3, None),
+             ("fb_shuffle", 4, "fat_tree"), ("mixed", 5, None))
+    # (wave_iters, cascade_iters, events, jct, cct) per lane
+    RECORD = [
+        (379, 152, 75,
+         {"serve#0": 281.001954704809, "serve#1": 360.33759879233895,
+          "serve#2": 420.3218178096331, "serve#3": 362.16550466510455},
+         {"serve#0": 255.68646328996147, "serve#1": 335.0221073774914,
+          "serve#2": 395.00632639478556, "serve#3": 336.8500132502569}),
+        (493, 204, 101,
+         {"train#0": 4.676020889949943, "train#1": 7.866070205297948,
+          "train#2": 10.4114648333797},
+         {"train#0": 4.671207700354515, "train#1": 7.86125701570252,
+          "train#2": 10.406651643784272}),
+        (481, 198, 98,
+         {"moe#0": 16.40380928565135, "moe#1": 23.1486298295184},
+         {"moe#0": 16.389515451075372, "moe#1": 23.134335994942425}),
+        (419, 168, 83,
+         {"serve#0": 191.55388503901415, "fb1#1": 79.68810673740006,
+          "train#2": 9.327975831922933, "serve#3": 247.8111611681394,
+          "fb1#4": 137.18511772468116},
+         {"serve#0": 174.676890762449, "fb1#1": 68.03812682579378,
+          "train#2": 9.323162642327505, "serve#3": 230.93416689157425,
+          "fb1#4": 125.5351378130749}),
+        (183, 66, 32,
+         {"fb0#0": 173.7314199780476, "fb1#1": 233.86458548499712,
+          "fb1#2": 197.7306473406121, "fb2#3": 156.02470330984906},
+         {"fb0#0": 100.0, "fb1#1": 136.13393814438507,
+          "fb1#2": 100.00000000000001, "fb2#3": 100.00000000000003}),
+        (566, 265, 139,
+         {"fb0#0": 37.79084860135734, "fb0#1": 38.042267319530104,
+          "fb1#2": 25.86194102886248, "fb0#3": 37.79084860135734,
+          "fb1#4": 36.80382691831409},
+         {"fb0#0": 21.82829694354603, "fb0#1": 22.079715661718787,
+          "fb1#2": 13.893873161258153, "fb0#3": 21.828296943546032,
+          "fb1#4": 31.30285447484837}),
+    ]
+
+    def test_counters_and_results_unchanged(self):
+        lanes = [pack_instance(*build_scenario(s, seed=i, quick=True,
+                                               lint=False, topology=t))
+                 for s, i, t in self.LANES]
+        got = [(r.wave_iters, r.cascade_iters, r.events, r.jct, r.cct)
+               for r in run_fifo_batch(lanes)]
+        assert got == self.RECORD
 
 class TestRecompilation:
     def test_one_trace_per_batch_shape(self):
