@@ -2,10 +2,21 @@
 
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration and a
 traffic mix.  Everything particular to them lives in files found by
-name: ``bench/workloads/<cell>.json`` (lanes, the limit of
-the check), ``bench/configs/<config>.json`` (sizes) with
+name: ``bench/workloads/<cell>.json`` (lanes, the limit of the check,
+and under ``pins`` the padded batch shape and the generator's
+fingerprint that the tests hold the cell to),
+``bench/configs/<config>.json`` (sizes) with
 ``bench/configs/<config>.py`` (``build_lanes``), and one reader per
-metric, ``bench/metrics/<metric>.py``.
+metric, ``bench/metrics/<metric>.py``.  So a new configuration and its
+cell take exactly these files, and no edit of a file that is there:
+
+* one ``configs`` entry and one ``workloads`` entry in ``BENCHMARK.json``;
+* ``bench/configs/<config>.json`` and ``bench/configs/<config>.py``;
+* ``bench/workloads/<cell>.json``.
+
+A new cell of a configuration that is there takes the ``workloads``
+entry and its file alone; a new metric, its ``per_layer`` or
+``end_to_end`` entry and ``bench/metrics/<metric>.py``.
 
 A sweep cell is what a user waits for: the cell's B seed-lanes built
 (generator, then strict lint), packed, run by the lockstep engine, and
@@ -26,6 +37,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import importlib
+import inspect
 import json
 import math
 import random
@@ -45,13 +57,17 @@ from repro.core.fabric import Fabric, make_topology
 from repro.core.metaflow import JobDAG
 
 ROOT = Path(__file__).resolve().parents[1]
-BENCH = ROOT / "bench"
 
 _LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 #: A traced run profiles the window's sweep cells until this much of the
 #: window has passed: a TPU trace holds one event per executed op, and
 #: collecting and reading a minute of them takes minutes.
 TRACE_SECONDS = 10.0
+#: ``simjax.LaneResult``'s counters; a sweep cell keeps the most over
+#: its lanes (the same for every lane where the engine counts a batch).
+COUNTERS = ("wave_iters", "cascade_iters", "batch_steps", "batch_syncs")
+#: The one fabric ``bench/reference.py`` routes.
+TOPOLOGY = "big_switch"
 
 
 # ------------------------------------------------------------------ spec
@@ -63,7 +79,8 @@ def load_spec(name: str) -> dict:
     if name not in cells:
         raise KeyError(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
-    traffic = json.loads((BENCH / "workloads" / f"{name}.json").read_text())
+    traffic = json.loads(
+        (ROOT / "bench" / "workloads" / f"{name}.json").read_text())
     if (traffic["config"], traffic["traffic"]) != (cell["config"],
                                                    cell["traffic"]):
         raise ValueError(f"{name}: traffic file names another cell")
@@ -72,8 +89,12 @@ def load_spec(name: str) -> dict:
         return [m["name"] for m in metrics
                 if name in m.get("workloads", [name])]
 
+    config = load_config(cell["config"])
+    if config["topology"] != TOPOLOGY:
+        raise ValueError(f"{name}: the reference routes only a "
+                         f"{TOPOLOGY}, not a {config['topology']}")
     return {"name": name, "chips": cell["chips"], "traffic": traffic,
-            "config": load_config(cell["config"]),
+            "config": config,
             "end_to_end": mine(bench["end_to_end"]),
             "per_layer": mine(bench["per_layer"]),
             "units": {m["name"]: m["unit"]
@@ -81,7 +102,8 @@ def load_spec(name: str) -> dict:
 
 
 def load_config(name: str) -> dict:
-    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+    return json.loads(
+        (ROOT / "bench" / "configs" / f"{name}.json").read_text())
 
 
 def lane_plan(seed: int, traffic: dict, config: dict
@@ -148,7 +170,8 @@ def plain_lane(jobs: list[JobDAG], config: dict) -> dict:
 # ------------------------------------------------------------ sweep cell
 @dataclasses.dataclass
 class CellRecord:
-    """Host-clock spans of one sweep cell and what it returned."""
+    """Host-clock spans of one sweep cell, what it returned, and the
+    engine's counters (``COUNTERS``; 0 where the cell raised)."""
 
     build_s: float
     pack_s: float
@@ -156,10 +179,20 @@ class CellRecord:
     end: float
     lane_events: list[int]
     results: list | None          # per lane (jct, cct), None if it raised
+    wave_iters: int = 0
+    cascade_iters: int = 0
+    batch_steps: int = 0
+    batch_syncs: int = 0
 
     @property
     def steps(self) -> int:
         return max(self.lane_events, default=0)
+
+
+def counters(results) -> dict[str, int]:
+    """The batch's ``COUNTERS`` from one engine call's ``LaneResult``s:
+    the most over its lanes."""
+    return {f: max(getattr(r, f) for r in results) for f in COUNTERS}
 
 
 def sweep_cell(plan, traffic: dict, config: dict) -> CellRecord:
@@ -182,7 +215,21 @@ def sweep_cell(plan, traffic: dict, config: dict) -> CellRecord:
     return CellRecord(
         build_s=t1 - t0, pack_s=t2 - t1, engine_s=t3 - t2, end=t3,
         lane_events=[r.events for r in res] if res else [],
-        results=[(r.jct, r.cct) for r in res] if res else None)
+        results=[(r.jct, r.cct) for r in res] if res else None,
+        **(counters(res) if res else {}))
+
+
+def engine_programs(lanes) -> list[str]:
+    """HLO text of the engine's two programs at the lanes' batch shape,
+    as ``simjax.run_fifo_batch`` runs them (its default window); loaded
+    from the compile cache once the lanes have run.  Their op metadata
+    names each op's scope (``trace_reduce.scope_map``)."""
+    window = inspect.signature(simjax.run_fifo_batch).parameters[
+        "steps_per_sync"].default
+    pk = simjax._pack_batch([simjax.pack_instance(f, j) for f, j in lanes])
+    st = simjax._init_state(pk)
+    return [simjax._multi_step_jit.lower(pk, st, window).compile().as_text(),
+            simjax._settle_jit.lower(pk, st).compile().as_text()]
 
 
 @dataclasses.dataclass
@@ -242,7 +289,7 @@ def check(spec: dict, plan, cells: list[CellRecord]
 
 
 # -------------------------------------------------------------------- run
-def _read_metrics(names: list[str], m: Measurements, units: dict) -> dict:
+def read_metrics(names: list[str], m: Measurements, units: dict) -> dict:
     out = {}
     for name in names:
         value = importlib.import_module(f"bench.metrics.{name}").read(m)
@@ -274,15 +321,21 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     window_start = time.perf_counter()
     cells: list[CellRecord] = []
     traced = 0
+
+    def stop_trace() -> int:
+        t = time.perf_counter()
+        jax.profiler.stop_trace()
+        print(f"profiler stopped after {len(cells)} sweep cells, in "
+              f"{time.perf_counter() - t:.3f} s", file=sys.stderr)
+        return len(cells)
+
     while time.perf_counter() - window_start < seconds:
         cells.append(sweep_cell(plan, traffic, config))
         if trace_dir and not traced and (
                 cells[-1].end - window_start >= TRACE_SECONDS):
-            jax.profiler.stop_trace()
-            traced = len(cells)
+            traced = stop_trace()
     if trace_dir and not traced:
-        jax.profiler.stop_trace()
-        traced = len(cells)
+        traced = stop_trace()
     traces1, compiles1 = simjax.trace_count(), compiles.n
     print(f"trace_count after the window: {traces1}", file=sys.stderr)
     starts = [window_start] + [c.end for c in cells[:-1]]
@@ -297,16 +350,26 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
                   "peak_bytes_in_use")}
     reduced = None
     if trace_dir:
-        reduced = trace_reduce.reduce(trace_reduce.load(trace_dir))
+        # Lowered only now, once the window's counts are closed.
+        t0 = time.perf_counter()
+        programs = engine_programs(build_lanes(plan, traffic, config))
+        t1 = time.perf_counter()
+        loaded = trace_reduce.load(trace_dir, programs)
+        t2 = time.perf_counter()
+        reduced = trace_reduce.reduce(loaded)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        print(f"trace read: programs {t1 - t0:.3f} s, load {t2 - t1:.3f} s, "
+              f"reduce {time.perf_counter() - t2:.3f} s, "
+              f"{sum(map(len, loaded['devices'].values()))} device ops",
+              file=sys.stderr)
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
 
     m = Measurements(lanes=len(plan), setup_s=window_start - t_start,
                      window_start=window_start, cells=cells, trace=reduced,
                      traced_cells=traced)
-    metrics = _read_metrics(spec["per_layer"] if trace
-                            else spec["end_to_end"], m, spec["units"])
+    metrics = read_metrics(spec["per_layer"] if trace
+                           else spec["end_to_end"], m, spec["units"])
 
     worst, failed = check(spec, plan, [warm] + cells)
     checks = {
@@ -323,7 +386,9 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     line = {"correct": correct, "attempted": len(plan) * len(cells),
             "failed": sum(failed[1:]), "metrics": metrics, "device": device}
     if reduced:
-        line["breakdown"] = {"device_ops": reduced["device_ops"],
-                             "idle_gaps": reduced["idle_gaps"]}
+        line["breakdown"] = {
+            k: reduced[k][:trace_reduce.TOP]
+            for k in ("device_ops", "idle_gaps", "device_phases")
+            if k in reduced}
     line["checks"] = checks
     return line

@@ -1,12 +1,14 @@
 """The benchmark's data: every cell, configuration and metric is found
-by name, and each configuration builds the lanes it stood for."""
+by name, each configuration builds the lanes it stood for, and a cell
+is added by new files alone."""
 
 from __future__ import annotations
 
+import filecmp
 import hashlib
 import importlib
 import json
-from pathlib import Path
+import shutil
 
 import numpy as np
 import pytest
@@ -16,18 +18,9 @@ pytest.importorskip("jax")
 from bench import harness, reference  # noqa: E402
 from repro.core import simjax  # noqa: E402
 
-HERE = Path(__file__).resolve().parent
 BENCH = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
 METRICS = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
-
-#: Padded batch shape of each cell's lanes (the engine's program shape):
-#: flows F, DAG nodes N, jobs J, dependency edges E, and the most flow
-#: legs on one link ML (CPU count).
-SHAPES = {
-    "fb2010.sweep20": {"F": 138, "N": 52, "J": 2, "E": 47, "ML": 22},
-    "fb2010.replay32": {"F": 601, "N": 256, "J": 32, "E": 196, "ML": 36},
-}
 
 
 def _identity_plan(spec):
@@ -48,7 +41,8 @@ def _padded(packed) -> dict:
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_files_load_by_name(name):
     spec = harness.load_spec(name)
-    assert spec["traffic"]["chips"] == spec["chips"] == 1
+    assert spec["traffic"]["chips"] == spec["chips"]
+    assert spec["chips"] in (1, 4)
     assert spec["config"]["name"] == spec["traffic"]["config"]
     assert importlib.import_module(
         f"bench.configs.{spec['config']['name']}").build_lanes
@@ -78,11 +72,14 @@ def test_configs_are_named_by_benchmark():
 
 @pytest.mark.parametrize("name", CELLS)
 def test_lanes_keep_their_padded_shape(name):
+    """The padded batch shape of the cell's lanes (the engine's program
+    shape) is the one its file pins."""
     spec = harness.load_spec(name)
+    shape = spec["traffic"]["pins"]["shape"]
     lanes = harness.build_lanes(_identity_plan(spec), spec["traffic"],
                                 spec["config"])
     packed = [simjax.pack_instance(f, j) for f, j in lanes]
-    assert _padded(packed) == SHAPES[name]
+    assert _padded(packed) == shape
     # Every seed's relabelling keeps the shape: the seed moves no work.
     for seed in (3, 2 ** 31 + 7):
         plan = harness.lane_plan(seed, spec["traffic"], spec["config"])
@@ -90,7 +87,7 @@ def test_lanes_keep_their_padded_shape(name):
             range(spec["traffic"]["lanes"]))
         lanes = harness.build_lanes(plan, spec["traffic"], spec["config"])
         assert _padded([simjax.pack_instance(f, j)
-                        for f, j in lanes]) == SHAPES[name]
+                        for f, j in lanes]) == shape
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -103,8 +100,55 @@ def test_generators_match_their_fingerprint(name):
     plain = [harness.plain_lane(jobs, spec["config"]) for _, jobs in lanes]
     digest = hashlib.sha256(
         json.dumps(plain, sort_keys=True).encode()).hexdigest()
-    want = json.loads((HERE / "fingerprints.json").read_text())[name]
-    assert digest == want["sha256"]
+    assert digest == spec["traffic"]["pins"]["sha256"]
+
+
+def test_a_cell_is_added_by_new_files_alone(tmp_path, monkeypatch):
+    """A copy of the benchmark with one more cell, ``fb2010.replay32``
+    under another name with its own pins: the per-cell tests pass on it,
+    and the copy differs from the benchmark only by the cell's file and
+    its ``BENCHMARK.json`` entry."""
+    root = tmp_path / "checkout"
+    shutil.copytree(harness.ROOT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    name, traffic_name = "fb2010.replay32b", "replay32b"
+    cell = next(w for w in BENCH["workloads"]
+                if w["name"] == "fb2010.replay32")
+    bench = json.loads(json.dumps(BENCH))
+    bench["workloads"].append(dict(cell, name=name, traffic=traffic_name))
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    traffic = json.loads(
+        (root / "bench" / "workloads" / "fb2010.replay32.json").read_text())
+    traffic["traffic"] = traffic_name
+    (root / "bench" / "workloads" / f"{name}.json").write_text(
+        json.dumps(traffic))
+
+    def unchanged(a, b):
+        cmp = filecmp.dircmp(a, b, ignore=["__pycache__"])
+        assert not cmp.diff_files and not cmp.left_only
+        assert set(cmp.right_only) <= {f"{name}.json"}
+        for sub in cmp.common_dirs:
+            unchanged(a / sub, b / sub)
+
+    unchanged(harness.ROOT / "bench", root / "bench")
+    assert {k: v for k, v in bench.items() if k != "workloads"} == {
+        k: v for k, v in BENCH.items() if k != "workloads"}
+    assert bench["workloads"][:-1] == BENCH["workloads"]
+
+    monkeypatch.setattr(harness, "ROOT", root)
+    test_cell_files_load_by_name(name)
+    test_lanes_keep_their_padded_shape(name)
+    test_generators_match_their_fingerprint(name)
+    assert harness.load_spec(name)["per_layer"] == harness.load_spec(
+        "fb2010.replay32")["per_layer"]
+
+
+def test_only_a_big_switch_is_taken(monkeypatch):
+    real = harness.load_config
+    monkeypatch.setattr(harness, "load_config", lambda name: dict(
+        real(name), topology="fat_tree"))
+    with pytest.raises(ValueError, match="big_switch"):
+        harness.load_spec("fb2010.sweep20")
 
 
 def test_relabelling_moves_no_result():

@@ -94,23 +94,46 @@ def test_float32_control_fails(tiny):
 
 
 def test_traced_run_reports_per_layer_metrics(tiny, monkeypatch):
-    """The profiler itself needs a chip; its reduction is fed a trace."""
+    """The profiler itself needs a chip; its reduction is fed a trace.
+    The engine's counters come from the run itself, on the CPU."""
     from bench import trace_reduce
 
     ms = 1e6
-    fake = {"devices": {"/device:TPU:0": [["%while.1", 10 * ms, 50 * ms]]},
+    step = "jit(_multi_step)/while/body"
+    fake = {"devices": {"/device:TPU:0": [
+                ["%while.1", 10 * ms, 50 * ms, "jit(_multi_step)/while"],
+                ["%fusion.2", 10 * ms, 20 * ms, f"{step}/simjax.madd/add"],
+                ["%fusion.3", 30 * ms, 10 * ms, f"{step}/simjax.backfill/min"],
+                ["%fusion.4", 40 * ms, 5 * ms, f"{step}/simjax.horizon/sub"],
+                ["%fusion.5", 45 * ms, 5 * ms, f"{step}/simjax.settle/or"]]},
             "spans": [["bench.cell", 0, 100 * ms],
                       ["bench.build", 0, 10 * ms],
-                      ["bench.engine", 10 * ms, 90 * ms]]}
-    monkeypatch.setattr(trace_reduce, "load", lambda d: fake)
+                      ["bench.engine", 10 * ms, 90 * ms],
+                      ["simjax.pack_batch", 60 * ms, 10 * ms],
+                      ["simjax.sync", 70 * ms, 20 * ms]]}
+    monkeypatch.setattr(trace_reduce, "load", lambda d, programs=(): fake)
     monkeypatch.setattr(harness, "TRACE_SECONDS", 0.0)
     line = _run(tiny, trace=True)
     assert line["correct"] is True
     assert set(line["metrics"]) == set(tiny["per_layer"])
     assert line["metrics"]["device_idle_share"]["value"] == pytest.approx(0.5)
+    for name in ("waves_per_step", "cascade_iters_per_step",
+                 "window_step_util"):
+        assert 0 < line["metrics"][name]["value"] < 100
+    assert line["metrics"]["window_step_util"]["value"] <= 1
     assert line["device"]["busy_s"] == pytest.approx(0.05)
     assert line["device"]["window_s"] == pytest.approx(0.1)
-    assert line["breakdown"] == {"device_ops": [["%while.1", 0.05]],
-                                 "idle_gaps": [["bench.engine", 0.04],
-                                               ["bench.build", 0.01]]}
+    want = {"device_ops": {"%fusion.2": 0.02, "%fusion.3": 0.01,
+                           "%while.1": 0.01, "%fusion.4": 0.005,
+                           "%fusion.5": 0.005},
+            "idle_gaps": {"simjax.sync": 0.02, "bench.engine": 0.01,
+                          "bench.build": 0.01, "simjax.pack_batch": 0.01},
+            "device_phases": {"simjax.madd": 0.02, "simjax.backfill": 0.01,
+                              "unscoped": 0.01, "simjax.horizon": 0.005,
+                              "simjax.settle": 0.005}}
+    assert set(line["breakdown"]) == set(want)
+    for key, got in line["breakdown"].items():
+        assert dict(got) == pytest.approx(want[key])
+        assert [v for _, v in got] == sorted((v for _, v in got),
+                                             reverse=True)
     assert list(line)[-1] == "checks"
