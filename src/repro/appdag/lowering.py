@@ -204,6 +204,30 @@ def lower_grouped(kind: str, groups: list[tuple[int, ...]], size: float,
                              tuple(merged))
 
 
+def rail_all_to_all(sizes, gpus_per_node: int
+                    ) -> list[tuple[int, int, FlowSpec]]:
+    """A node-aware all-to-all over rails: one leg per (rank, node).
+
+    ``sizes[r][n]`` is what rank ``r`` sends to node ``n``; ranks are
+    ports, numbered node-major (``r = m * gpus_per_node + i`` is GPU
+    ``i`` of node ``m``).  A leg crosses the inter-node fabric once, to
+    the GPU of the same in-node index (its rail): flow ``(m, i) ->
+    (n, i)`` for every ``n != m``, and the target node forwards it
+    inside (NVLink, not on this fabric).  Legs of size 0 are dropped.
+    Returns ``(r, n, (src, dst, size))`` per leg, rank-major; the
+    reverse exchange (node ``n`` back to rank ``r``) is each flow with
+    its ends swapped.  Unlike ``all_to_all``, every leg has its own
+    size and no leg waits on another."""
+    legs = []
+    for r, row in enumerate(sizes):
+        m, i = divmod(r, gpus_per_node)
+        for n, size in enumerate(row):
+            if n != m and size > 0:
+                legs.append((r, n, (r, n * gpus_per_node + i,
+                                    float(size))))
+    return legs
+
+
 def add_lowered(job, name: str, lowered: LoweredCollective,
                 deps: list[str] | None = None) -> str | None:
     """Emit a lowered collective into ``job`` as chained metaflows.

@@ -35,10 +35,13 @@ from __future__ import annotations
 import random
 from dataclasses import dataclass
 
-from repro.appdag.plans import (PlanAxes, dense_train_dag, moe_train_dag,
-                                pipeline_serve_dag)
+import numpy as np
+
+from repro.appdag.plans import (PlanAxes, dense_train_dag, ep_stage_dag,
+                                moe_train_dag, pipeline_serve_dag)
 from repro.configs import get_config
 from repro.configs.base import LM_SHAPES
+from repro.configs.deepseek_v3 import CONFIG as DEEPSEEK_V3
 from repro.core.fabric import Fabric, make_topology
 from repro.core.metaflow import JobDAG
 from repro.core.workload import build_job, synth_fb_coflow
@@ -238,6 +241,22 @@ def scenario_fb_shuffle(seed: int = 0, quick: bool = False):
     return n_ports, jobs
 
 
+def scenario_dsv3_ep64(seed: int = 0, quick: bool = False):
+    """DeepSeek-V3's expert-parallel training stage (arXiv:2412.19437
+    §3.1-3.3): one EP group of 64 H800s, 8 to a node, each a port of a
+    big switch at its 50 GB/s InfiniBand NIC, running 2 MoE layers
+    forward and backward for one microbatch of 4,096 tokens a rank.
+    One job, routed from ``seed`` (``plans.ep_stage_dag``): 448 legs an
+    all-to-all, sized by the routing, each its own metaflow.  ``quick``
+    keeps 2 of the 8 nodes (16 ports, 16 legs an all-to-all), a size
+    the CPU tests run in seconds."""
+    ep = 16 if quick else 64
+    job = ep_stage_dag(DEEPSEEK_V3, np.random.default_rng(seed),
+                       moe_layers=2, tokens_per_rank=4096, ep=ep,
+                       gpus_per_node=8, bias_sigma=0.1, sample=128)
+    return ep, [job]
+
+
 SCENARIOS = {
     "dense_dp": scenario_dense_dp,
     "moe_ep": scenario_moe_ep,
@@ -245,6 +264,7 @@ SCENARIOS = {
     "mixed": scenario_mixed,
     "mixed_oversub_3to1": scenario_mixed_oversub,
     "fb_shuffle": scenario_fb_shuffle,
+    "dsv3_ep64": scenario_dsv3_ep64,
 }
 
 # Default network topology per scenario (big_switch when absent); any

@@ -16,6 +16,9 @@ every logical collective through ``appdag.lowering``:
   ``pipeline_serve_dag`` GPipe-style pipelined prefill: the (stage x
                          microbatch) compute grid with per-boundary
                          activation p2p metaflows.
+  ``ep_stage_dag``       one expert-parallel group's share of a pipeline
+                         stage, per rank: routed, node-limited rail
+                         all-to-alls whose every leg is its own metaflow.
 
 Port-numbering convention (DESIGN.md §9): one fabric port per device,
 ``rank(pp_i, dp_i, tp_i) = port_base + (pp_i * dp + dp_i) * tp + tp_i``,
@@ -34,7 +37,10 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from repro.appdag.lowering import add_lowered, lower_grouped
+import numpy as np
+
+from repro.appdag.lowering import add_lowered, lower_grouped, rail_all_to_all
+from repro.appdag.routing import dispatch_bytes, route
 from repro.configs.base import (ModelConfig, ShapeConfig, active_param_count,
                                 param_count)
 from repro.core.metaflow import JobDAG
@@ -167,7 +173,7 @@ def _train_dag(cfg: ModelConfig, shape: ShapeConfig, plan: PlanAxes,
     # Split the unit's grads into expert vs dense(shared) buckets; both
     # are zero-expert for dense configs.  TP shards every bucket
     # ``tp``-ways; experts additionally shard over EP.
-    D, F = cfg.d_model, cfg.d_ff
+    D, F = cfg.d_model, cfg.expert_ff
     moe_layers = sum(1 for i in range(cfg.n_layers) if cfg.is_moe_layer(i))
     # With ep == 1 experts are DP-replicated like any other param, so they
     # stay in the dense bucket (and the expert bucket is empty).
@@ -309,5 +315,134 @@ def pipeline_serve_dag(cfg: ModelConfig, plan: PlanAxes, *,
                 deps.append(f"x{s}m{m}")
             job.add_task(f"c{s}m{m}", load=stage_load,
                          machine=plan.rank(s, 0, 0, port_base), deps=deps)
+    job.validate()
+    return job
+
+
+# ------------------------------------------------- expert-parallel stage
+#: NVIDIA H800 SXM, as DeepSeek-V3 was trained on (arXiv:2412.19437
+#: §3.1): the H100 SXM's dense peaks, FLOP/s, and one 400 Gb/s
+#: InfiniBand NIC, bytes/s.
+H800_BF16_FLOPS = 989.5e12
+H800_FP8_FLOPS = 1979e12
+H800_NIC_BW = 50e9
+#: Share of the dense peak a training GEMM reaches (assumed).
+GEMM_EFFICIENCY = 0.5
+
+
+def mla_flops_per_token(cfg: ModelConfig, seq_len: int) -> float:
+    """Forward FLOPs of one token through an MLA block: the low-rank
+    query and key/value projections, the output projection, and causal
+    attention over a ``seq_len`` sequence (the mean token sees
+    ``(seq_len + 1) / 2`` keys)."""
+    d, h = cfg.d_model, cfg.n_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    proj = (d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
+            + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+            + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + h * cfg.v_head_dim * d)
+    keys = (seq_len + 1) / 2
+    return 2.0 * (proj + h * (qk + cfg.v_head_dim) * keys)
+
+
+def expert_flops_per_token(cfg: ModelConfig) -> float:
+    """Forward FLOPs of one token through one expert's gated FFN."""
+    return 2.0 * 3 * cfg.d_model * cfg.expert_ff
+
+
+def ep_stage_dag(cfg: ModelConfig, rng: np.random.Generator, *,
+                 moe_layers: int, tokens_per_rank: int, ep: int,
+                 gpus_per_node: int, bias_sigma: float,
+                 sample: int) -> JobDAG:
+    """One EP group's share of a pipeline stage: ``moe_layers`` MoE
+    layers forward, then backward, for one microbatch of
+    ``tokens_per_rank`` tokens (one sequence) on each of ``ep`` ranks.
+
+    Each layer is routed once (``routing.route``; the backward reuses
+    the forward's routing).  Rank ``r`` is port ``r``, on node
+    ``r // gpus_per_node``, and each all-to-all is a rail all-to-all
+    (``lowering.rail_all_to_all``): every leg is a one-flow metaflow
+    gated only by its producer, so a node's experts wait on their own
+    incoming legs and not on the whole exchange.  Forward layer ``l``,
+    rank ``r``, node ``n``:
+
+      * ``f{l}/A{r}``: MLA, the shared experts and the router on rank
+        ``r``; needs ``f{l-1}/E{node(r)}`` and the legs ``f{l-1}/C*>{r}``
+        (the stage's input is there at the start);
+      * ``f{l}/D{r}>{n}``: dispatch, ``r``'s tokens for node ``n`` in
+        FP8 (``routing.dispatch_bytes``); needs ``f{l}/A{r}``;
+      * ``f{l}/E{n}``: the node's routed experts, as long as its busiest
+        rank; needs the legs ``f{l}/D*>{n}`` and the node's ``A`` tasks;
+      * ``f{l}/C{n}>{r}``: combine, the same tokens back in BF16; needs
+        ``f{l}/E{n}``.
+
+    ``turn{r}`` (no load) is rank ``r``'s forward end, where its
+    backward starts.  The backward ``b{l}/...`` has the same four kinds
+    with layers in reverse: ``D`` carries the combine's gradient from
+    rank to experts, ``C`` the dispatch's gradient back, both BF16; its
+    ``D`` and ``E`` wait on ``b{l+1}/A`` (or ``turn``) where the forward
+    waits on ``A``, and its compute is twice the forward's.
+
+    Compute loads are FLOPs at the config's widths over an H800's
+    dense peak times ``GEMM_EFFICIENCY``: BF16 for MLA, the shared
+    experts and the router, FP8 for the routed experts.  Sizes are in
+    the time one MB takes on a NIC (port capacity 1.0 moves one MB per
+    unit).  ``job.meta["route_stats"]`` keeps each layer's
+    ``RouteStats``."""
+    mb = 1e6
+    unit_s = mb / H800_NIC_BW
+    n_nodes = ep // gpus_per_node
+    d_bytes, c_bytes = dispatch_bytes(cfg), 2.0 * cfg.d_model
+    dense = (mla_flops_per_token(cfg, tokens_per_rank)
+             + cfg.n_shared_experts * expert_flops_per_token(cfg)
+             + 2.0 * cfg.d_model * cfg.n_experts)
+    a_load = (tokens_per_rank * dense
+              / (H800_BF16_FLOPS * GEMM_EFFICIENCY) / unit_s)
+    pair_load = (expert_flops_per_token(cfg)
+                 / (H800_FP8_FLOPS * GEMM_EFFICIENCY) / unit_s)
+    routes = [route(cfg, ep, gpus_per_node, tokens_per_rank, rng,
+                    bias_sigma=bias_sigma, sample=sample)
+              for _ in range(moe_layers)]
+    node_ranks = [range(n * gpus_per_node, (n + 1) * gpus_per_node)
+                  for n in range(n_nodes)]
+
+    job = JobDAG(name=f"{cfg.name}-ep{ep}-stage")
+    job.meta["route_stats"] = tuple(rt.stats for rt in routes)
+    senders = [f"f0/A{r}" for r in range(ep)]
+    for r in range(ep):
+        job.add_task(senders[r], load=a_load, machine=r)
+    # The exchanges in the order they run: forward by layer, backward in
+    # reverse; each one's receivers are the next one's senders.
+    order = ([("f", k) for k in range(moe_layers)]
+             + [("b", k) for k in reversed(range(moe_layers))])
+    for phase, k in order:
+        p, rt = f"{phase}{k}", routes[k]
+        grow, d_size = (1.0, d_bytes) if phase == "f" else (2.0, c_bytes)
+        legs = rail_all_to_all(rt.node_tokens, gpus_per_node)
+        into: list[list[str]] = [[] for _ in range(n_nodes)]
+        for r, n, (src, dst, tok) in legs:
+            into[n].append(f"{p}/D{r}>{n}")
+            job.add_metaflow(into[n][-1], [(src, dst, tok * d_size / mb)],
+                             deps=[senders[r]])
+        for n in range(n_nodes):
+            busiest = max(rt.pairs[r] for r in node_ranks[n])
+            job.add_task(f"{p}/E{n}", load=grow * busiest * pair_load,
+                         machine=n * gpus_per_node,
+                         deps=into[n] + [senders[r] for r in node_ranks[n]])
+        back: list[list[str]] = [[] for _ in range(ep)]
+        for r, n, (src, dst, tok) in legs:
+            back[r].append(f"{p}/C{n}>{r}")
+            job.add_metaflow(back[r][-1], [(dst, src, tok * c_bytes / mb)],
+                             deps=[f"{p}/E{n}"])
+        if phase == "b":
+            receivers, load = [f"b{k}/A{r}" for r in range(ep)], 2 * a_load
+        elif k + 1 < moe_layers:
+            receivers, load = [f"f{k + 1}/A{r}" for r in range(ep)], a_load
+        else:
+            receivers, load = [f"turn{r}" for r in range(ep)], 0.0
+        for r in range(ep):
+            job.add_task(receivers[r], load=load, machine=r,
+                         deps=[f"{p}/E{r // gpus_per_node}"] + back[r])
+        senders = receivers
     job.validate()
     return job

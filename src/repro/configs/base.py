@@ -36,6 +36,19 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     moe_ep: bool = False           # expert-parallel buffers (needs E >= mesh model size)
+    d_expert: int = 0              # routed expert width; 0 -> d_ff
+    n_expert_groups: int = 0       # router groups (DeepSeek: one per node)
+    groups_per_token: int = 0      # groups a token may reach (node limit)
+    n_shared_experts: int = 0      # always-on experts beside the routed ones
+    first_dense_layers: int = 0    # leading layers with a dense FFN
+    router_scoring: str = "softmax"  # softmax | sigmoid
+
+    # --- multi-head latent attention (MLA; 0 = plain attention) ---
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- attention variants ---
     sliding_window: int = 0        # 0 = full attention; >0 = SWA window
@@ -69,6 +82,11 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def expert_ff(self) -> int:
+        """Width of one routed expert's FFN."""
+        return self.d_expert or self.d_ff
+
+    @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
@@ -87,8 +105,8 @@ class ModelConfig:
         return True
 
     def is_moe_layer(self, i: int) -> bool:
-        return self.is_moe and (i % self.moe_layer_period
-                                == self.moe_layer_period - 1)
+        return (self.is_moe and i >= self.first_dense_layers
+                and i % self.moe_layer_period == self.moe_layer_period - 1)
 
     @property
     def sub_quadratic(self) -> bool:

@@ -105,6 +105,9 @@ class JobDAG:
     metaflows: dict[str, Metaflow] = field(default_factory=dict)
     arrival: float = 0.0
     finish_time: float | None = None
+    #: How the job was built (e.g. an EP stage's routing statistics);
+    #: no simulator reads it, and ``instantiate`` does not copy it.
+    meta: dict = field(default_factory=dict)
 
     # ------------------------------------------------------------- builders
     def add_task(self, name: str, load: float, machine: int = -1,

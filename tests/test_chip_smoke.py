@@ -29,10 +29,10 @@ def smoke():
 def test_registered_phase(smoke):
     info = smoke.phase_registered(seeds=range(2), oracle_seeds=(0, 1),
                                   quick=True)
-    assert info["cells"] == 6 * 2 + 1
+    assert info["cells"] == 7 * 2 + 1
     assert info["max_abs_diff"] <= smoke.TOL
-    assert set(info["batches"]) == {"dense_dp", "fb_shuffle", "mixed",
-                                    "mixed_oversub_3to1", "moe_ep",
+    assert set(info["batches"]) == {"dense_dp", "dsv3_ep64", "fb_shuffle",
+                                    "mixed", "mixed_oversub_3to1", "moe_ep",
                                     "pipe_serve"}
     assert all(b["events"] > 0 for b in info["batches"].values())
 
