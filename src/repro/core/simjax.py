@@ -11,17 +11,17 @@ flows, path length, links, routes) and finished lanes are masked
 no-ops, so a batch needs ``max(per-lane events)`` steps, not the union.
 
 Two structural choices keep the step fast on CPU XLA, where scatter
-serializes: every segment reduction (flow→metaflow, flow→job,
-edge→node, (job, link) demand) is a *static-permutation prefix-sum* —
+serializes: every segment count of the settle cascade (flow→metaflow,
+flow→job, edge→node, node→job) is a *static-permutation prefix-sum* —
 the index arrays are sorted at pack time, so a reduction is cumsum +
 two gathers — and both sequential sweeps (the MADD walk, the backfill)
 run as priority *waves*: any group whose contended links are free of
 higher-priority pending groups executes now, which reproduces the
 sequential order link-by-link (flows sharing a link always execute in
 key order across waves) while finishing in a handful of iterations.
-Inside a backfill wave no index is read: every flow↔link lookup is a
-masked reduction over a static flow × link mask, because a TPU runs a
-gather one element at a time.
+The MADD's (job, link) demand and every flow↔link lookup inside a
+backfill wave read no index: each is a masked reduction over a static
+flow × link mask, because a TPU runs a gather one element at a time.
 
 The numpy core stays the oracle (the ``simref.ReferenceSimulator``
 pattern): results agree per-lane on JCT/CCT within float tolerance —
@@ -215,7 +215,6 @@ class _Batch(NamedTuple):
     flow_node: jnp.ndarray      # [B, F] i4 (pad N, sorted)
     flow_job: jnp.ndarray       # [B, F] i4 (pad J, sorted)
     flow_size: jnp.ndarray      # [B, F] f8 (pad 0)
-    flow_links: jnp.ndarray     # [B, F, L] i4 (pad K)
     flow_pathid: jnp.ndarray    # [B, F] i4 (pad R)
     flow_pos: jnp.ndarray       # [B, F] i8 position within its metaflow
     link_cap: jnp.ndarray       # [B, K+1] f8 (pad/dummy inf)
@@ -225,9 +224,6 @@ class _Batch(NamedTuple):
     ne_bounds: jnp.ndarray      # edge_child  -> nodes   [B, N+2]
     jn_bounds: jnp.ndarray      # node_job    -> jobs    [B, J+2]
     jf_bounds: jnp.ndarray      # flow_job    -> jobs    [B, J+2]
-    # (job, link) demand segments over the flat (flow, leg) space
-    jl_perm: jnp.ndarray        # [B, F*L] i4  sort by job*(K+1)+link
-    jl_bounds: jnp.ndarray      # [B, (J+1)*(K+1)+1] i4
     # flow x link incidence: flow f crosses real link k (dummy K never)
     link_mask: jnp.ndarray      # [B, F, K+1] bool
 
@@ -261,18 +257,14 @@ def _bounds(ids: np.ndarray, n_segs: int) -> np.ndarray:
 
 
 def _seg_sum(vals: jnp.ndarray, bounds: jnp.ndarray) -> jnp.ndarray:
-    """Sum ``vals`` ([B, M]) over the static segments described by
-    ``bounds`` ([B, D+1]) — prefix sum + two gathers, no scatter.
+    """Count ``vals`` (integer, [B, M]) over the static segments
+    described by ``bounds`` ([B, D+1]) — prefix sum + two gathers, no
+    scatter.
 
-    Float prefixes are an explicit ``associative_scan``: ``jnp.cumsum``
-    lowers to a length-M ``reduce_window``, which the TPU compiler takes
-    minutes to build in emulated float64 (integer windows compile in
-    well under a second there, so counts keep ``cumsum``)."""
-    if jnp.issubdtype(vals.dtype, jnp.floating):
-        cs = lax.associative_scan(jnp.add, vals, axis=1)
-    else:
-        cs = jnp.cumsum(vals, axis=1)
-    cs = jnp.pad(cs, ((0, 0), (1, 0)))
+    Integer only: ``jnp.cumsum`` lowers to a length-M ``reduce_window``,
+    which the TPU compiler builds in well under a second for integers
+    but takes minutes over in emulated float64."""
+    cs = jnp.pad(jnp.cumsum(vals, axis=1), ((0, 0), (1, 0)))
     bi = jnp.arange(vals.shape[0])[:, None]
     return cs[bi, bounds[:, 1:]] - cs[bi, bounds[:, :-1]]
 
@@ -335,19 +327,10 @@ def _pack_batch(lanes: Sequence[PackedInstance]) -> _Batch:
         speed[b] = p.machine_speed
 
     # --- static reduction machinery (all id arrays above are sorted)
-    K1 = K + 1
     nf_bounds = np.stack([_bounds(flow_node[b], N + 1) for b in range(B)])
     ne_bounds = np.stack([_bounds(edge_child[b], N + 1) for b in range(B)])
     jn_bounds = np.stack([_bounds(node_job[b], J + 1) for b in range(B)])
     jf_bounds = np.stack([_bounds(flow_job[b], J + 1) for b in range(B)])
-
-    links_flat = flow_links.reshape(B, F * L)
-    jl_key = np.repeat(flow_job, L, axis=1).astype(np.int64) * K1 + links_flat
-    jl_perm = np.argsort(jl_key, axis=1, kind="stable").astype(np.int32)
-    jl_bounds = np.stack([
-        _bounds(np.take_along_axis(jl_key, jl_perm.astype(np.int64),
-                                   axis=1)[b], (J + 1) * K1)
-        for b in range(B)])
 
     # The mask counts a link once per flow, which stands for the route
     # only if no route crosses a link twice.
@@ -356,15 +339,14 @@ def _pack_batch(lanes: Sequence[PackedInstance]) -> _Batch:
         raise ValueError("a route crosses one link twice")
     real = flow_links < K
     b_ix, f_ix, _ = np.nonzero(real)
-    link_mask = np.zeros((B, F, K1), dtype=bool)
+    link_mask = np.zeros((B, F, K + 1), dtype=bool)
     link_mask[b_ix, f_ix, flow_links[real]] = True
 
     return _Batch(*map(jnp.asarray, (
         arrival, job_valid, node_job, node_is_mf, node_load, node_pend0,
         node_valid, edge_parent, flow_node, flow_job, flow_size,
-        flow_links, flow_pathid, flow_pos, link_cap, speed,
-        nf_bounds, ne_bounds, jn_bounds, jf_bounds,
-        jl_perm, jl_bounds, link_mask)))
+        flow_pathid, flow_pos, link_cap, speed,
+        nf_bounds, ne_bounds, jn_bounds, jf_bounds, link_mask)))
 
 
 def _init_state(pk: _Batch) -> _State:
@@ -458,6 +440,24 @@ def _settle(pk: _Batch, s: _State) -> _State:
 
 
 # --------------------------------------------------------------------- kick
+def _demand(pk: _Batch, w: jnp.ndarray, job: jnp.ndarray) -> jnp.ndarray:
+    """Job ``job``'s demand on each link, ``[B, K+1]``: the sum of ``w``
+    ([B, F], never negative) over the job's flows that cross the link.
+
+    A masked sum over the flow axis of ``link_mask``, with no gather:
+    the TPU runs a gather one element at a time.  A link that no flow of
+    the job with positive weight crosses sums exact ``+0.0``s, so
+    ``dem > 0`` marks exactly the links the job uses; the dummy link's
+    column is empty, so padded legs add nothing.  One job at a time, so
+    that the chip's compiler keeps the links in the vector lanes and
+    sums the flows across vector rows, as in the backfill waves: over
+    all jobs at once (``[B, F, J+1, K+1]``) it lays the flows in the
+    lanes where flows outnumber links, at 4 to 17 times the cost of an
+    element (DESIGN.md §17)."""
+    on = pk.link_mask & (pk.flow_job == job)[:, :, None]
+    return jnp.where(on, w[:, :, None], 0.0).sum(axis=1)
+
+
 def _kick(pk: _Batch, s: _State) -> _State:
     """One fifo decision + fluid advance per lane: MADD each job's
     coflow (all its active metaflows) in job-priority order on the
@@ -468,27 +468,18 @@ def _kick(pk: _Batch, s: _State) -> _State:
     B, F = s.flow_rem.shape
     J1 = pk.arrival.shape[1]
     N1 = pk.node_job.shape[1]
-    L = pk.flow_links.shape[2]
-    K1 = pk.link_cap.shape[1]
     bi = jnp.arange(B)[:, None]
 
     with jax.named_scope("simjax.madd"):
-        # --- MADD walk: all (job, link) demands in one prefix pass, then a
-        # scan whose body is elementwise on [B, links].
+        # --- MADD walk: a scan over jobs in fifo order whose body is a
+        # masked reduction and elementwise work on [B, links] and
+        # [B, flows]; no index is read but each flow's node state.
         live = (s.node_state[bi, pk.flow_node] == 1) & (s.flow_rem > EPS)
         w = jnp.where(live, s.flow_rem, 0.0)
-        w_fl = jnp.repeat(w, L, axis=1)[bi, pk.jl_perm]
-        # The prefix sum is a reassociated tree scan, so an *empty* segment's
-        # prefix difference can leave ±ulp-of-prefix residue instead of an
-        # exact 0.0 — and a phantom "used" link on an exhausted residual
-        # would wrongly refuse the whole MADD.  An integer count of live
-        # contributors is exact; it gates which segments carry demand.
-        cnt = _seg_sum((w_fl > 0.0).astype(jnp.int32), pk.jl_bounds)
-        dem_all = jnp.where(cnt > 0, _seg_sum(w_fl, pk.jl_bounds),
-                            0.0).reshape(B, J1, K1)
 
-        def madd(carry, dem):
-            res, gamma_ok = carry                  # dem: [B, K1] for this job
+        def madd(carry, j):
+            res, rates = carry
+            dem = _demand(pk, w, j)
             used = dem > 0.0
             blocked = (used & (res <= EPS)).any(axis=1)
             gamma = jnp.where(used & (res > EPS), dem / res, 0.0).max(axis=1)
@@ -496,16 +487,12 @@ def _kick(pk: _Batch, s: _State) -> _State:
             safe = jnp.where(ok, gamma, 1.0)
             res = jnp.where(ok[:, None],
                             jnp.clip(res - dem / safe[:, None], 0.0, None), res)
-            return (res, gamma_ok), (ok, safe)
+            rates = jnp.where(live & (pk.flow_job == j) & ok[:, None],
+                              s.flow_rem / safe[:, None], rates)
+            return (res, rates), None
 
-        (res, _), (ok_j, gamma_j) = lax.scan(
-            madd, (pk.link_cap, None), jnp.moveaxis(dem_all[:, :J1 - 1], 0, 1))
-        ok_j = jnp.concatenate([jnp.moveaxis(ok_j, 0, 1),
-                                jnp.zeros((B, 1), dtype=bool)], axis=1)
-        gamma_j = jnp.concatenate([jnp.moveaxis(gamma_j, 0, 1),
-                                   jnp.ones((B, 1))], axis=1)
-        rates = jnp.where(live & ok_j[bi, pk.flow_job],
-                          s.flow_rem / gamma_j[bi, pk.flow_job], 0.0)
+        (res, rates), _ = lax.scan(madd, (pk.link_cap, jnp.zeros((B, F))),
+                                   jnp.arange(J1 - 1, dtype=jnp.int32))
 
     with jax.named_scope("simjax.backfill"):
         # --- backfill: priority key = (job, metaflow activation order, flow

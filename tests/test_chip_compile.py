@@ -9,8 +9,8 @@ refuses, or one that brings back a float64 ``reduce-window`` (what
 compile in emulated float64), fails here in seconds instead of on the
 chip.  The compiled programs must also keep the engine's named scopes in
 their op names and its loop counters in 32 bits, and the backfill wave
-loop must stay free of gathers: the TPU runs a gather one element at a
-time, about 10 ns each.
+loop and the MADD's demand must stay free of gathers: the TPU runs a
+gather one element at a time, about 10 ns each.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
@@ -125,3 +125,14 @@ def test_backfill_wave_body_has_no_gather(programs, scenario):
     # The scope is in the program, so an empty list is not vacuous.
     assert "simjax.backfill/while/body/" in text
     assert _gathers_under(text, "simjax.backfill/while/body") == []
+
+
+@pytest.mark.parametrize("scenario", ["pipe_serve", "dense_dp"])
+def test_madd_demand_has_no_gather(programs, scenario):
+    text = programs(scenario)["step_text"]
+    # The scope is in the program, so the count is not vacuous.
+    assert "simjax.madd/" in text
+    # The (job, link) demand is a masked reduction and each flow's rate
+    # is set where its job index matches the scan's; what the MADD still
+    # gathers is each flow's node state, for the live flows.
+    assert len(_gathers_under(text, "simjax.madd")) == 1

@@ -23,12 +23,14 @@ import pytest
 jax = pytest.importorskip(
     "jax", reason="the lockstep engine is optional: everything else "
                   "runs on the numpy core without JAX installed")
+jnp = jax.numpy
 
 from repro.appdag.mixer import SCENARIOS, build_scenario  # noqa: E402
 from repro.core import Fabric, JobDAG, make_scheduler, simulate  # noqa: E402
 from repro.core.fabric import Topology, make_topology  # noqa: E402
-from repro.core.simjax import (LaneResult, _pack_batch,  # noqa: E402
-                               pack_instance, run_fifo_batch, trace_count)
+from repro.core.simjax import (LaneResult, _demand,  # noqa: E402
+                               _pack_batch, pack_instance, run_fifo_batch,
+                               trace_count)
 
 TOL = 1e-6
 N_SEEDS = 5
@@ -198,19 +200,104 @@ class TestLinkMask:
             run_fifo_batch([lane])
 
 
+class TestDemand:
+    """The MADD's (job, link) demand, a masked sum over the flow axis,
+    against a plain loop over each lane's flows and their routes."""
+
+    def test_demand_equals_a_loop_over_routes(self):
+        built = [build_scenario(s, seed=i, quick=True, lint=False,
+                                topology=t)
+                 for i, (s, t) in enumerate((
+                     ("pipe_serve", None), ("mixed_oversub_3to1", None),
+                     ("fb_shuffle", "fat_tree"), ("mixed", None)))]
+        assert {f.topology.kind for f, _ in built} == {
+            "big_switch", "leaf_spine", "fat_tree"}
+        lanes = [pack_instance(f, j) for f, j in built]
+        pk = _pack_batch(lanes)
+        B, F = pk.flow_size.shape
+        J1, K1 = pk.arrival.shape[1], pk.link_cap.shape[1]
+        rng = np.random.default_rng(7)
+        # Weights over six decades; every odd job has drained (weight 0)
+        # while the even jobs still load the same links.
+        w = np.zeros((B, F))
+        want = np.zeros((B, J1, K1))
+        drained = []
+        for b, p in enumerate(lanes):
+            flow_job = p.node_job[p.flow_node]
+            for f, (j, route) in enumerate(zip(flow_job, p.flow_links)):
+                if j % 2:
+                    continue
+                w[b, f] = 10.0 ** rng.uniform(-3, 3)
+                for k in route:
+                    if k < p.n_links:
+                        want[b, j, k] += w[b, f]
+            drained += [(b, j) for j in set(flow_job.tolist()) if j % 2]
+        assert drained
+
+        got = np.stack([np.asarray(_demand(pk, jnp.asarray(w), j))
+                        for j in range(J1)], axis=1)
+        assert got.shape == (B, J1, K1)
+        assert ((got == 0.0) == (want == 0.0)).all()
+        used = want > 0.0
+        assert np.abs(got[used] / want[used] - 1.0).max() < 1e-12
+        # A (job, link) whose flows have all drained reads exactly zero,
+        # on links the other jobs still load.
+        for b, j in drained:
+            assert (got[b, j] == 0.0).all()
+        assert (got[:, :, -1] == 0.0).all()      # the dummy link
+        assert (got[:, -1] == 0.0).all()         # the dummy job
+
+
 class TestRecordedBatch:
     """One fixed batch of registered scenarios on all three topologies
-    gives, lane by lane, exactly the loop counters, events, JCTs and CCTs
-    recorded from the engine while its backfill waves still gathered
-    through per-link lists of flow legs.  The masked reductions take the
-    same minima and make the same single grant per link, so every number
-    is bit-identical."""
+    gives, lane by lane, exactly the recorded loop counters, events,
+    JCTs and CCTs.  The backfill's masked reductions take the same
+    minima and make the same single grant per link as the per-link
+    gathers they replaced, bit for bit.  The MADD's masked demand sum
+    adds a (job, link)'s flows in another order than the prefix sums it
+    replaced, so JCTs and CCTs moved in their last bits (at most 2e-13)
+    and the counters not at all: ``RECORD_GATHERED`` keeps the numbers
+    of the prefix-sum demand, within 1e-9 of today's."""
 
     LANES = (("pipe_serve", 0, None), ("dense_dp", 1, None),
              ("moe_ep", 2, None), ("mixed_oversub_3to1", 3, None),
              ("fb_shuffle", 4, "fat_tree"), ("mixed", 5, None))
     # (wave_iters, cascade_iters, events, jct, cct) per lane
     RECORD = [
+        (379, 152, 75,
+         {"serve#0": 281.0019547048092, "serve#1": 360.33759879233907,
+          "serve#2": 420.3218178096332, "serve#3": 362.16550466510466},
+         {"serve#0": 255.6864632899616, "serve#1": 335.0221073774915,
+          "serve#2": 395.0063263947857, "serve#3": 336.850013250257}),
+        (493, 204, 101,
+         {"train#0": 4.67602088994994, "train#1": 7.866070205297936,
+          "train#2": 10.411464833379688},
+         {"train#0": 4.671207700354511, "train#1": 7.861257015702508,
+          "train#2": 10.40665164378426}),
+        (481, 198, 98,
+         {"moe#0": 16.40380928565132, "moe#1": 23.148629829518363},
+         {"moe#0": 16.389515451075347, "moe#1": 23.13433599494239}),
+        (419, 168, 83,
+         {"serve#0": 191.55388503901415, "fb1#1": 79.6881067374001,
+          "train#2": 9.327975831922732, "serve#3": 247.8111611681394,
+          "fb1#4": 137.18511772468116},
+         {"serve#0": 174.676890762449, "fb1#1": 68.03812682579382,
+          "train#2": 9.323162642327304, "serve#3": 230.93416689157425,
+          "fb1#4": 125.5351378130749}),
+        (183, 66, 32,
+         {"fb0#0": 173.7314199780476, "fb1#1": 233.86458548499718,
+          "fb1#2": 197.73064734061217, "fb2#3": 156.02470330984912},
+         {"fb0#0": 100.0, "fb1#1": 136.13393814438507,
+          "fb1#2": 100.00000000000004, "fb2#3": 100.00000000000003}),
+        (566, 265, 139,
+         {"fb0#0": 37.79084860135737, "fb0#1": 38.042267319530126,
+          "fb1#2": 25.861941028862514, "fb0#3": 37.79084860135737,
+          "fb1#4": 36.803826918314115},
+         {"fb0#0": 21.82829694354606, "fb0#1": 22.079715661718822,
+          "fb1#2": 13.893873161258167, "fb0#3": 21.828296943546068,
+          "fb1#4": 31.3028544748484}),
+    ]
+    RECORD_GATHERED = [
         (379, 152, 75,
          {"serve#0": 281.001954704809, "serve#1": 360.33759879233895,
           "serve#2": 420.3218178096331, "serve#3": 362.16550466510455},
@@ -252,6 +339,13 @@ class TestRecordedBatch:
         got = [(r.wave_iters, r.cascade_iters, r.events, r.jct, r.cct)
                for r in run_fifo_batch(lanes)]
         assert got == self.RECORD
+        for now, then in zip(got, self.RECORD_GATHERED):
+            assert now[:3] == then[:3]
+            for times, times_then in zip(now[3:], then[3:]):
+                assert times.keys() == times_then.keys()
+                for name, t in times.items():
+                    assert t == pytest.approx(times_then[name], rel=0,
+                                              abs=1e-9), name
 
 class TestRecompilation:
     def test_one_trace_per_batch_shape(self):
