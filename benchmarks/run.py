@@ -6,7 +6,6 @@ fails (exit 1) if any bench's check() finds a regression.
   fig3_topologies  — paper Fig 3b topology sweep, two workload regimes
   comm_overlap     — MSA on our own training-step DAG (all archs)
   sched_micro      — scheduler decision latency + decision caching
-  roofline_table   — §Roofline summary from dry-run artifacts
 
 Scheduling policies resolve through the ``repro.core.sched`` registry;
 ``--policy NAME`` (repeatable) overrides the policy set for the benches
@@ -42,7 +41,7 @@ from repro.core.sched import available_policies
 from repro.experiments import topology_arg
 
 from benchmarks import (comm_overlap, fig1_motivation, fig3_topologies,
-                        ml_workloads, roofline_table, sched_micro)
+                        ml_workloads, sched_micro)
 
 BENCHES = {
     "fig1_motivation": fig1_motivation,
@@ -50,7 +49,6 @@ BENCHES = {
     "comm_overlap": comm_overlap,
     "ml_workloads": ml_workloads,
     "sched_micro": sched_micro,
-    "roofline_table": roofline_table,
 }
 
 
@@ -131,14 +129,6 @@ def main() -> None:
                        "rows": json_rows, "failures": failures},
                       fh, indent=1, sort_keys=True)
             fh.write("\n")
-
-    if args.only is None or args.only == "roofline_table":
-        print()
-        print("== Roofline (single-pod) ==")
-        print(roofline_table.table("single"))
-        print()
-        print("== Roofline (multi-pod) ==")
-        print(roofline_table.table("multi"))
 
     if failures:
         sys.exit(1)

@@ -1,8 +1,8 @@
 """``repro.appdag`` — compile real ML parallelism plans into metaflow DAGs.
 
-The bridge between the two halves of this repo: the JAX substrate's model
-configs and parallelism axes (DP/TP/PP/EP) on one side, the scheduling
-core's ``JobDAG`` workloads on the other.  Its layers (DESIGN.md §9):
+The bridge from published model configs (``repro.configs``) and
+parallelism axes (DP/TP/PP/EP) to the scheduling core's ``JobDAG``
+workloads.  Its layers (DESIGN.md §9):
 
   ``lowering``  logical collectives -> per-port flow rounds with exact
                 byte accounting (ring / halving-doubling / direct), and

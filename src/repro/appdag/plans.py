@@ -42,7 +42,7 @@ import numpy as np
 from repro.appdag.lowering import add_lowered, lower_grouped, rail_all_to_all
 from repro.appdag.routing import dispatch_bytes, route
 from repro.configs.base import (ModelConfig, ShapeConfig, active_param_count,
-                                param_count)
+                                n_units, param_count)
 from repro.core.metaflow import JobDAG
 from repro.roofline.analysis import HBM_BW, LINK_BW, PEAK_FLOPS
 
@@ -104,18 +104,6 @@ class PlanAxes:
 
 
 # ------------------------------------------------------------ config math
-def n_units(cfg: ModelConfig) -> int:
-    """Scan-unit count, from the config alone (mirrors
-    ``models.transformer.unit_layout`` without importing JAX)."""
-    if cfg.family == "hybrid":
-        unit_len = cfg.attn_layer_period
-    elif cfg.is_moe and cfg.moe_layer_period > 1:
-        unit_len = cfg.moe_layer_period
-    else:
-        unit_len = 1
-    return max(1, cfg.n_layers // unit_len)
-
-
 def _embed_params(cfg: ModelConfig) -> int:
     return cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2)
 

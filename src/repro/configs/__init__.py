@@ -1,11 +1,11 @@
-"""Architecture registry: the 10 assigned configs + smoke twins."""
+"""Architecture registry: the 10 assigned configs (``ARCH_NAMES``)."""
 
 from __future__ import annotations
 
 import importlib
 
 from repro.configs.base import (LM_SHAPES, ModelConfig, ShapeConfig,
-                                active_param_count, param_count, shapes_for)
+                                active_param_count, n_units, param_count)
 
 _MODULES = {
     "mixtral-8x22b": "mixtral_8x22b",
@@ -24,18 +24,11 @@ ARCH_NAMES = tuple(_MODULES)
 
 
 def get_config(name: str) -> ModelConfig:
-    if name.endswith("-smoke"):
-        return get_config(name[:-len("-smoke")]).smoke()
     if name not in _MODULES:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(_MODULES)}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
     return mod.CONFIG
 
 
-def all_configs() -> dict[str, ModelConfig]:
-    return {n: get_config(n) for n in ARCH_NAMES}
-
-
 __all__ = ["ARCH_NAMES", "LM_SHAPES", "ModelConfig", "ShapeConfig",
-           "active_param_count", "all_configs", "get_config", "param_count",
-           "shapes_for"]
+           "active_param_count", "get_config", "n_units", "param_count"]

@@ -2,14 +2,15 @@
 
 One ``ModelConfig`` dataclass covers every assigned architecture family
 (dense / MoE / SSM / hybrid / enc-dec / VLM / audio); per-arch files in this
-package instantiate it with the exact published dimensions plus a reduced
-``smoke`` twin for CPU tests.  The FULL configs are only ever lowered with
-``jax.eval_shape`` / ``.lower()`` (no allocation) — see launch/dryrun.py.
+package instantiate it with the exact published dimensions.  Nothing here
+builds a model: ``repro.appdag`` and ``core/comm_schedule.py`` read the
+configs analytically (parameter counts, scan units) to size the
+communication DAGs of a training step.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 
 
 @dataclass(frozen=True)
@@ -90,11 +91,6 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
-    @property
-    def attends(self) -> bool:
-        """Has any attention layers at all."""
-        return self.family != "ssm"
-
     def is_attn_layer(self, i: int) -> bool:
         if self.family == "ssm":
             return False
@@ -108,72 +104,33 @@ class ModelConfig:
         return (self.is_moe and i >= self.first_dense_layers
                 and i % self.moe_layer_period == self.moe_layer_period - 1)
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """Whether a 500k-token decode is architecturally in-contract."""
-        if self.family in ("ssm", "hybrid"):
-            return True
-        return self.sliding_window > 0
-
-    def smoke(self, **overrides) -> ModelConfig:
-        """Reduced same-family twin for CPU smoke tests."""
-        small = dict(
-            n_layers=max(2, min(4, self.n_layers)),
-            d_model=64,
-            n_heads=4,
-            n_kv_heads=min(self.n_kv_heads, 4) if self.n_kv_heads < self.n_heads else 4,
-            head_dim=16,
-            d_ff=128,
-            vocab_size=256,
-            dtype="float32",
-        )
-        if self.is_moe:
-            small.update(n_experts=min(self.n_experts, 4),
-                         experts_per_token=min(self.experts_per_token, 2))
-        if self.family in ("ssm", "hybrid"):
-            small.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
-        if self.family == "hybrid":
-            small.update(n_layers=self.attn_layer_period,  # one full block
-                         attn_layer_period=self.attn_layer_period)
-        if self.n_enc_layers:
-            small.update(n_enc_layers=2)
-        if self.sliding_window:
-            small.update(sliding_window=32)
-        if self.n_prefix_tokens:
-            small.update(n_prefix_tokens=8)
-        small.update(overrides)
-        return replace(self, name=self.name + "-smoke", **small)
-
 
 @dataclass(frozen=True)
 class ShapeConfig:
-    """One assigned (input-shape) cell: what to lower and at what size."""
+    """One input shape: sequence length and global batch of a step."""
 
     name: str
     seq_len: int
     global_batch: int
-    kind: str                      # train | prefill | decode
 
 
 LM_SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
+    "train_4k": ShapeConfig("train_4k", 4_096, 256),
 }
 
 
-def shapes_for(cfg: ModelConfig) -> dict[str, ShapeConfig]:
-    """The shape cells that are in-contract for this architecture.
-
-    ``long_500k`` needs sub-quadratic attention: it runs for SSM / hybrid /
-    SWA archs and is skipped (documented in DESIGN.md §5) for pure
-    full-attention ones.
-    """
-    out = dict(LM_SHAPES)
-    if not cfg.sub_quadratic:
-        out.pop("long_500k")
-    return out
+def n_units(cfg: ModelConfig) -> int:
+    """Scan-unit count: the number of repeated layer blocks a training
+    step walks, and so of its per-unit gradient buckets.  A hybrid's
+    unit is one attention period; an interleaved MoE's is one MoE
+    period; otherwise a unit is one layer."""
+    if cfg.family == "hybrid":
+        unit_len = cfg.attn_layer_period
+    elif cfg.is_moe and cfg.moe_layer_period > 1:
+        unit_len = cfg.moe_layer_period
+    else:
+        unit_len = 1
+    return max(1, cfg.n_layers // unit_len)
 
 
 def param_count(cfg: ModelConfig) -> int:

@@ -5,8 +5,8 @@ d_model=7168 128H MLA (q_lora 1536, kv_lora 512, qk_nope 128, qk_rope
 64, v 128), dense d_ff=18432 in the first 3 layers, then MoE: 256
 routed experts of width 2048, 8 a token, sigmoid scores (noaux_tc) over
 8 groups of which a token reaches 4, plus 1 shared expert; vocab 129280.
-The scheduling plans (``appdag.plans.ep_stage_dag``) read it; no JAX
-model of the substrate builds MLA, so it is not in ``ARCH_NAMES``.
+The scheduling plans (``appdag.plans.ep_stage_dag``) read it; it is not
+one of the ten ``ARCH_NAMES`` that the step-plan sweeps cover.
 """
 from repro.configs.base import ModelConfig
 

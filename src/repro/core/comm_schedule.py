@@ -19,22 +19,18 @@ egress/ingress pair whose capacity is the link bandwidth; a ring
 reduce-scatter of ``bytes`` pushes ~``bytes`` through each device's link.
 
 Outputs:
-  * a static bucket priority order (realized in HLO by
-    parallel/collectives.py via optimization-barrier chaining), and
+  * a static bucket priority order (the MSA schedule's first-service
+    order of the buckets), and
   * simulated step times under msa / varys / fifo / flat-barrier sync —
-    the §Perf evidence for the overlap win.
-
-XLA-scan caveat (DESIGN.md §8): inside a scanned layer loop all units share
-one collective instruction, so the explicit ordered sync applies to
-unrolled-unit training (examples/train_lm.py) and to the bucket *sizing*
-of the scanned path.
+    the evidence for the overlap win (``benchmarks/comm_overlap.py``).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-from repro.configs.base import ModelConfig, ShapeConfig, param_count
+from repro.configs.base import (ModelConfig, ShapeConfig, active_param_count,
+                                n_units, param_count)
 from repro.core.metaflow import JobDAG
 from repro.core.sched import make_scheduler
 from repro.core.simulator import simulate
@@ -43,8 +39,6 @@ from repro.roofline.analysis import HBM_BW, LINK_BW, PEAK_FLOPS
 
 def unit_param_bytes(cfg: ModelConfig) -> float:
     """Parameter bytes of one scan unit (bf16), excluding embeddings."""
-    from repro.models.transformer import n_units
-
     D, V = cfg.d_model, cfg.vocab_size
     embed = V * D * (1 if cfg.tie_embeddings else 2)
     total = param_count(cfg) - embed
@@ -54,9 +48,6 @@ def unit_param_bytes(cfg: ModelConfig) -> float:
 def unit_bwd_seconds(cfg: ModelConfig, shape: ShapeConfig,
                      chips: int = 256) -> float:
     """Roofline estimate of one unit's backward+recompute time per step."""
-    from repro.configs.base import active_param_count
-    from repro.models.transformer import n_units
-
     D, V = cfg.d_model, cfg.vocab_size
     embed = V * D * (1 if cfg.tie_embeddings else 2)
     active = active_param_count(cfg) - embed
@@ -84,8 +75,6 @@ def build_train_dag(cfg: ModelConfig, shape: ShapeConfig, chips: int = 256,
     barrier variant: one metaflow carrying every bucket, all optimizer
     updates gated on it (classic end-of-step all-reduce).
     """
-    from repro.models.transformer import n_units
-
     U = n_units(cfg)
     bwd = unit_bwd_seconds(cfg, shape, chips)
     bytes_u = unit_param_bytes(cfg) / chips        # FSDP shard per device
@@ -114,8 +103,6 @@ def build_train_dag(cfg: ModelConfig, shape: ShapeConfig, chips: int = 256,
 
 def plan_step_comm(cfg: ModelConfig, shape: ShapeConfig, chips: int = 256,
                    link_bw: float = LINK_BW) -> StepCommPlan:
-    from repro.models.transformer import n_units
-
     U = n_units(cfg)
     steps: dict[str, float] = {}
     for policy in ("msa", "varys", "fifo"):

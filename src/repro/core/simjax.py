@@ -7,7 +7,7 @@ flow→links table, dedup backfill, per-flow event horizons — to jitted
 JAX so B seeds/scenario-instances advance **in lockstep** as stacked
 arrays: one dispatch serves lane 0's event 312 and lane 19's event 87
 simultaneously.  Lanes are padded to the batch maxima (jobs, DAG nodes,
-flows, path length, links, routes) and finished lanes are masked
+flows, path length, links) and finished lanes are masked
 no-ops, so a batch needs ``max(per-lane events)`` steps, not the union.
 
 Two structural choices keep the step fast on CPU XLA, where scatter
@@ -65,9 +65,9 @@ import numpy as np
 import jax
 
 # The engine is compared against a float64 oracle; JAX defaults to f32.
-# The flag is global, but every other JAX user in this repo
-# (src/repro/kernels) pins dtypes explicitly, so flipping it here is
-# safe for mixed test processes.
+# The flag is global, but this engine is the only module under src/ that
+# imports JAX (tests/test_simjax.py checks it), so nothing else in a
+# process that loads it meets the flipped default.
 jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402  (after the x64 flag, deliberately)
@@ -114,10 +114,8 @@ class PackedInstance:
     flow_node: np.ndarray               # [F] i4  owning metaflow node
     flow_size: np.ndarray               # [F] f8
     flow_links: np.ndarray              # [F, L] i4, short paths padded
-    flow_pathid: np.ndarray             # [F] i4  equal iff same (src, dst)
     link_cap: np.ndarray                # [n_links] f8
     n_links: int
-    n_routes: int
     machine_speed: float
 
 
@@ -125,8 +123,8 @@ def pack_instance(fabric: Fabric, jobs: Sequence[JobDAG],
                   machine_speed: float = 1.0) -> PackedInstance:
     """Flatten ``(fabric, jobs)`` into the array form the batched engine
     consumes.  Mirrors ``Simulator._build_tables``: job order, node
-    order, flow order, deterministic routes, and the per-``(src, dst)``
-    ``pathid`` keys all match the numpy core."""
+    order, flow order and deterministic routes all match the numpy
+    core."""
     for j in jobs:
         j.validate()
     names = [j.name for j in jobs]
@@ -145,8 +143,6 @@ def pack_instance(fabric: Fabric, jobs: Sequence[JobDAG],
     flow_node: list[int] = []
     flow_size: list[float] = []
     flow_paths: list[tuple[int, ...]] = []
-    flow_pathid: list[int] = []
-    route_ids: dict[tuple[int, int], int] = {}
 
     for ji, job in enumerate(jobs):
         for name in list(job.tasks) + list(job.metaflows):
@@ -168,8 +164,6 @@ def pack_instance(fabric: Fabric, jobs: Sequence[JobDAG],
                 flow_node.append(nid)
                 flow_size.append(float(f.size))
                 flow_paths.append(tuple(topo.path(f.src, f.dst)))
-                flow_pathid.append(
-                    route_ids.setdefault((f.src, f.dst), len(route_ids)))
 
     n_links = fabric.n_links
     max_len = max((len(p) for p in flow_paths), default=1)
@@ -189,10 +183,8 @@ def pack_instance(fabric: Fabric, jobs: Sequence[JobDAG],
         flow_node=np.asarray(flow_node, dtype=np.int32),
         flow_size=np.asarray(flow_size, dtype=np.float64),
         flow_links=links,
-        flow_pathid=np.asarray(flow_pathid, dtype=np.int32),
         link_cap=np.asarray(fabric.cap, dtype=np.float64).copy(),
         n_links=n_links,
-        n_routes=len(route_ids),
         machine_speed=float(machine_speed),
     )
 
@@ -201,8 +193,7 @@ class _Batch(NamedTuple):
     """Stacked lanes, padded to batch maxima, plus the static index
     machinery for scatter-free reductions.  Dummy slots: job ``J``
     (arrival=inf, invalid), node ``N`` (pend huge, never activates),
-    link ``K`` (cap=inf, absorbs padded path positions), route ``R``
-    (collects padded flows, which are never live)."""
+    link ``K`` (cap=inf, absorbs padded path positions)."""
 
     arrival: jnp.ndarray        # [B, J+1] f8 (pad inf)
     job_valid: jnp.ndarray      # [B, J+1] bool
@@ -215,7 +206,6 @@ class _Batch(NamedTuple):
     flow_node: jnp.ndarray      # [B, F] i4 (pad N, sorted)
     flow_job: jnp.ndarray       # [B, F] i4 (pad J, sorted)
     flow_size: jnp.ndarray      # [B, F] f8 (pad 0)
-    flow_pathid: jnp.ndarray    # [B, F] i4 (pad R)
     flow_pos: jnp.ndarray       # [B, F] i8 position within its metaflow
     link_cap: jnp.ndarray       # [B, K+1] f8 (pad/dummy inf)
     speed: jnp.ndarray          # [B] f8
@@ -277,7 +267,6 @@ def _pack_batch(lanes: Sequence[PackedInstance]) -> _Batch:
     F = max(p.flow_node.size for p in lanes)
     L = max(p.flow_links.shape[1] for p in lanes)
     K = max(p.n_links for p in lanes)
-    R = max(p.n_routes for p in lanes)
 
     arrival = np.full((B, J + 1), np.inf)
     job_valid = np.zeros((B, J + 1), dtype=bool)
@@ -292,7 +281,6 @@ def _pack_batch(lanes: Sequence[PackedInstance]) -> _Batch:
     flow_job = np.full((B, F), J, dtype=np.int32)
     flow_size = np.zeros((B, F))
     flow_links = np.full((B, F, L), K, dtype=np.int32)
-    flow_pathid = np.full((B, F), R, dtype=np.int32)
     flow_pos = np.zeros((B, F), dtype=np.int64)
     link_cap = np.full((B, K + 1), np.inf)
     speed = np.empty(B)
@@ -314,7 +302,6 @@ def _pack_batch(lanes: Sequence[PackedInstance]) -> _Batch:
         flow_size[b, :f] = p.flow_size
         flow_links[b, :f, :p.flow_links.shape[1]] = np.where(
             p.flow_links == p.n_links, K, p.flow_links)
-        flow_pathid[b, :f] = p.flow_pathid
         # Position within the owning metaflow: flows are packed
         # metaflow-contiguously, so each group is a run of equal
         # flow_node values.
@@ -345,7 +332,7 @@ def _pack_batch(lanes: Sequence[PackedInstance]) -> _Batch:
     return _Batch(*map(jnp.asarray, (
         arrival, job_valid, node_job, node_is_mf, node_load, node_pend0,
         node_valid, edge_parent, flow_node, flow_job, flow_size,
-        flow_pathid, flow_pos, link_cap, speed,
+        flow_pos, link_cap, speed,
         nf_bounds, ne_bounds, jn_bounds, jf_bounds, link_mask)))
 
 

@@ -1,150 +1,13 @@
-"""Roofline terms from compiled dry-run artifacts.
+"""TPU v5e per-chip hardware constants.
 
-Per (arch x shape x mesh) cell:
-
-  compute term    = HLO_FLOPs / (chips * peak_FLOP/s)
-  memory term     = HLO_bytes / (chips * HBM_bw)
-  collective term = collective_bytes / (chips * link_bw)
-
-HLO_FLOPs / HLO_bytes come from ``compiled.cost_analysis()``; collective
-bytes from parsing ``compiled.as_text()`` (operand sizes of all-gather /
-all-reduce / reduce-scatter / all-to-all / collective-permute).
-
-Scan caveat (measured, see tests/test_roofline.py): XLA cost analysis does
-NOT multiply while-loop bodies by their trip count, and loop-body
-collectives appear once in the HLO text regardless of depth.  We therefore
-compile each cell at two reduced depths with the unit scan UNROLLED and
-extrapolate:   total(U) = f(a) + (U - a) * (f(b) - f(a)) / (b - a)
-which is exact when every unit lowers identically (they do — units are a
-scan in the real program).  The full-depth looped compile still provides
-memory_analysis() and the compile-success proof.
+The training-step DAGs (``appdag.plans``, ``core/comm_schedule.py``) size
+their compute nodes and transfers from these: a unit's backward is its
+FLOPs at ``PEAK_FLOPS``, an optimizer update its bytes at ``HBM_BW``, and
+a gradient bucket its bytes at ``LINK_BW``.
 """
 
 from __future__ import annotations
 
-import re
-from dataclasses import dataclass
-
-# TPU v5e hardware constants (per chip), from the assignment.
 PEAK_FLOPS = 197e12          # bf16 FLOP/s
 HBM_BW = 819e9               # bytes/s
 LINK_BW = 50e9               # bytes/s/link (ICI)
-
-_DTYPE_BYTES = {
-    "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3": 1, "f8e5m2": 1,
-    "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
-    "s32": 4, "u32": 4, "f32": 4,
-    "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16,
-}
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                "collective-permute")
-
-_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
-_OP_RE = re.compile(
-    r"=\s*(?:\([^)]*\)|[a-z0-9]+\[[0-9,]*\][^ ]*)\s+"
-    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start|-done)?\(([^)]*)\)")
-
-
-def _shape_bytes(text: str) -> int:
-    total = 0
-    for dt, dims in _SHAPE_RE.findall(text):
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-def collective_bytes(hlo_text: str) -> dict[str, int]:
-    """Sum operand bytes per collective kind over the HLO text.
-
-    Counts ``-start`` ops only once (the ``-done`` has no operands of its
-    own in the operand-shape syntax we parse).
-    """
-    out: dict[str, int] = {k: 0 for k in _COLLECTIVES}
-    for line in hlo_text.splitlines():
-        line = line.strip()
-        m = _OP_RE.search(line)
-        if not m:
-            continue
-        kind, operands = m.group(1), m.group(2)
-        if f"{kind}-done" in line.split("=")[-1]:
-            continue
-        b = _shape_bytes(operands)
-        if b == 0:
-            # operand shapes not printed: fall back to the result shape
-            b = _shape_bytes(line.split("=")[1].split(kind)[0])
-        out[kind] += b
-    return out
-
-
-def total_collective_bytes(hlo_text: str) -> int:
-    return sum(collective_bytes(hlo_text).values())
-
-
-@dataclass(frozen=True)
-class RooflineTerms:
-    flops: float                # global HLO flops for one step
-    hbm_bytes: float            # global bytes accessed
-    coll_bytes: float           # global collective bytes (operand sums)
-    chips: int
-
-    @property
-    def compute_s(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS)
-
-    @property
-    def memory_s(self) -> float:
-        return self.hbm_bytes / (self.chips * HBM_BW)
-
-    @property
-    def collective_s(self) -> float:
-        return self.coll_bytes / (self.chips * LINK_BW)
-
-    @property
-    def dominant(self) -> str:
-        terms = {"compute": self.compute_s, "memory": self.memory_s,
-                 "collective": self.collective_s}
-        return max(terms, key=terms.get)
-
-    @property
-    def bound_s(self) -> float:
-        return max(self.compute_s, self.memory_s, self.collective_s)
-
-    def as_dict(self) -> dict:
-        return {
-            "flops": self.flops, "hbm_bytes": self.hbm_bytes,
-            "coll_bytes": self.coll_bytes, "chips": self.chips,
-            "compute_s": self.compute_s, "memory_s": self.memory_s,
-            "collective_s": self.collective_s, "dominant": self.dominant,
-        }
-
-
-def extrapolate(a_units: int, a_val: float, b_units: int, b_val: float,
-                units: int) -> float:
-    """Linear depth extrapolation from two unrolled reduced-depth compiles."""
-    if b_units == a_units:
-        return b_val
-    marg = (b_val - a_val) / (b_units - a_units)
-    return max(a_val + (units - a_units) * marg, 0.0)
-
-
-def model_flops_per_step(cfg, shape) -> float:
-    """MODEL_FLOPS: 6*N*D for dense, 6*N_active*D for MoE (train);
-    2*N*D (+2x for... no: forward-only) for prefill; 2*N_active per token
-    for decode."""
-    from repro.configs.base import active_param_count
-
-    n_active = active_param_count(cfg)
-    tokens = shape.global_batch * shape.seq_len
-    if shape.kind == "train":
-        return 6.0 * n_active * tokens
-    if shape.kind == "prefill":
-        return 2.0 * n_active * tokens
-    # decode: one token per sequence
-    return 2.0 * n_active * shape.global_batch

@@ -9,10 +9,11 @@ import pytest
 
 from repro.appdag import (PlanAxes, build_scenario, dense_train_dag,
                           lower_collective, lower_grouped, moe_train_dag,
-                          pipeline_serve_dag, poisson_mix, JobTemplate)
+                          n_units, pipeline_serve_dag, poisson_mix,
+                          JobTemplate)
 from repro.appdag.lowering import add_lowered
 from repro.appdag.mixer import comm_balanced
-from repro.configs import get_config
+from repro.configs import ARCH_NAMES, get_config
 from repro.configs.base import LM_SHAPES
 from repro.core import JobDAG, make_scheduler, simulate
 
@@ -188,6 +189,22 @@ class TestPlans:
         ranks = [plan.rank(p, d, t) for p in range(2) for d in range(4)
                  for t in range(2)]
         assert sorted(ranks) == list(range(16))
+
+
+# Scan units per assigned architecture, as the per-layer model code gave
+# them when it still counted them: the step DAGs of ``appdag.plans`` and
+# ``core/comm_schedule.py`` hold one gradient bucket per unit.
+UNITS = {
+    "mixtral-8x22b": 56, "llama4-maverick-400b-a17b": 48, "whisper-base": 6,
+    "llama3-405b": 126, "qwen2-7b": 28, "qwen1.5-4b": 40,
+    "deepseek-coder-33b": 62, "mamba2-370m": 48, "llava-next-34b": 60,
+    "jamba-1.5-large-398b": 9,
+}
+
+
+@pytest.mark.parametrize("arch", ARCH_NAMES)
+def test_n_units_matches_recorded(arch):
+    assert n_units(get_config(arch)) == UNITS[arch]
 
 
 # ----------------------------------------------------------------- mixer

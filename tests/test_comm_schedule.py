@@ -1,35 +1,25 @@
-"""Metaflow/MSA integration with the training step.
-
-1. The step-DAG plan: MSA beats the flat barrier, matches/beats FIFO.
-2. The HLO order of ordered collectives matches the MSA priority order
-   (the paper's schedule, pinned in the compiled artifact).
-"""
-
-import os
-import re
-import subprocess
-import sys
-import textwrap
+"""Metaflow/MSA integration with the training step: on every assigned
+architecture the step-DAG plan's MSA order is no worse than the flat
+barrier or Varys, and it orders every scan unit's bucket once."""
 
 import pytest
 
-from repro.configs import get_config
-from repro.configs.base import LM_SHAPES
+from repro.configs import ARCH_NAMES, get_config
+from repro.configs.base import LM_SHAPES, n_units
 from repro.core.comm_schedule import plan_step_comm
 
 
 class TestStepPlan:
-    @pytest.mark.parametrize("arch", ["qwen2-7b", "llama3-405b",
-                                      "mixtral-8x22b"])
+    @pytest.mark.parametrize("arch", ARCH_NAMES)
     def test_msa_no_worse_than_barrier(self, arch):
         plan = plan_step_comm(get_config(arch), LM_SHAPES["train_4k"])
         assert plan.dag_steps["msa"] <= plan.dag_steps["flat"] + 1e-9
         assert plan.dag_steps["msa"] <= plan.dag_steps["varys"] + 1e-9
 
-    def test_order_is_permutation(self):
-        cfg = get_config("qwen2-7b")
+    @pytest.mark.parametrize("arch", ARCH_NAMES)
+    def test_order_is_permutation(self, arch):
+        cfg = get_config(arch)
         plan = plan_step_comm(cfg, LM_SHAPES["train_4k"])
-        from repro.models.transformer import n_units
         assert sorted(plan.order) == list(range(n_units(cfg)))
 
     def test_msa_order_prioritizes_late_backward_units(self):
@@ -45,55 +35,3 @@ class TestStepPlan:
         plan = plan_step_comm(get_config("llama3-405b"),
                               LM_SHAPES["train_4k"])
         assert 0.0 <= plan.overlap_fraction <= 1.0
-
-
-_HLO_PROBE = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    from repro.parallel.collectives import ordered_psum
-
-    mesh = jax.make_mesh((4,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-    buckets = [jnp.zeros((8,)), jnp.zeros((16,)), jnp.zeros((32,)),
-               jnp.zeros((64,))]
-    order = [2, 0, 3, 1]
-
-    def f(*bs):
-        return tuple(ordered_psum(list(bs), order, "data"))
-
-    sf = shard_map(f, mesh=mesh, in_specs=(P(),) * 4, out_specs=(P(),) * 4)
-    txt = jax.jit(sf).lower(*buckets).compile().as_text()
-    import re
-    sizes = []
-    for line in txt.splitlines():
-        m = re.search(r"f32\\[(\\d+)\\][^=]*all-reduce", line)
-        if m and "all-reduce-start" not in line:
-            sizes.append(int(m.group(1)))
-        m2 = re.search(r"all-reduce-start\\(", line)
-    print("ORDER:", sizes)
-""")
-
-
-@pytest.mark.slow
-class TestHLOOrder:
-    def test_hlo_allreduce_order_matches_msa_order(self, tmp_path):
-        """Compile ordered_psum with a shuffled priority order on a 4-way
-        mesh; the all-reduce sequence in scheduled HLO must follow it."""
-        script = tmp_path / "probe.py"
-        script.write_text(_HLO_PROBE)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(os.path.dirname(__file__), "..", "src"),
-             env.get("PYTHONPATH", "")])
-        out = subprocess.run([sys.executable, str(script)], env=env,
-                             capture_output=True, text=True, timeout=300)
-        assert out.returncode == 0, out.stderr[-2000:]
-        m = re.search(r"ORDER: \[([0-9, ]+)\]", out.stdout)
-        assert m, out.stdout
-        sizes = [int(x) for x in m.group(1).split(",")]
-        # order [2,0,3,1] over sizes [8,16,32,64] -> [32, 8, 64, 16]
-        assert sizes == [32, 8, 64, 16], \
-            f"HLO all-reduce order {sizes} != MSA priority order [32,8,64,16]"

@@ -11,11 +11,17 @@ Three contracts:
      (hypothesis-randomized when available, pinned cases always);
   3. one jit trace per batch shape: re-running a shape recompiles
      nothing (``trace_count`` guard).
+
+The engine turns on JAX's process-wide ``jax_enable_x64`` flag at
+import; ``test_simjax_is_the_only_jax_user`` keeps that safe by keeping
+every other module under ``src/`` free of JAX.
 """
 
 from __future__ import annotations
 
 import random
+import re
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -472,3 +478,12 @@ class TestRunnerIntegration:
             for name, val in ref["result"][key].items():
                 assert recs[0]["result"][key][name] == \
                     pytest.approx(val, abs=TOL)
+
+
+def test_simjax_is_the_only_jax_user():
+    src = Path(__file__).resolve().parent.parent / "src"
+    jax_import = re.compile(r"^\s*(import|from)\s+jax\b", re.MULTILINE)
+    users = sorted(p.relative_to(src).as_posix()
+                   for p in src.rglob("*.py")
+                   if jax_import.search(p.read_text()))
+    assert users == ["repro/core/simjax.py"]
