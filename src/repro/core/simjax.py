@@ -238,6 +238,7 @@ class _State(NamedTuple):
     # integers are emulated on the TPU).
     waves: jnp.ndarray          # [B] i4  backfill wave iterations
     cascades: jnp.ndarray       # [B] i4  settle cascade iterations
+    steps: jnp.ndarray          # []  i4  lockstep steps the device ran
 
 
 def _bounds(ids: np.ndarray, n_segs: int) -> np.ndarray:
@@ -359,6 +360,7 @@ def _init_state(pk: _Batch) -> _State:
         events=jnp.zeros(B, dtype=jnp.int64),
         waves=jnp.zeros(B, dtype=jnp.int32),
         cascades=jnp.zeros(B, dtype=jnp.int32),
+        steps=jnp.zeros((), dtype=jnp.int32),
     )
 
 
@@ -568,9 +570,20 @@ def _step(pk: _Batch, s: _State) -> _State:
 
 
 def _multi_step(pk: _Batch, s: _State, n: int) -> _State:
-    """``n`` lockstep events in one device program — the host only
-    syncs (reads the done/deadlock flags) once per window."""
-    return lax.fori_loop(0, n, lambda _, st: _step(pk, st), s)
+    """Lockstep events in one device program while some lane is neither
+    done nor deadlocked, at most ``n`` of them: the program stops on the
+    device at the last lane's end, and the host reads the flags once,
+    after it.  The state's ``steps`` counts the steps run."""
+    def body(carry):
+        i, st = carry
+        st = _step(pk, st)
+        return i + 1, st._replace(steps=st.steps + 1)
+
+    def running(carry):
+        i, st = carry
+        return (i < n) & ~(st.done | st.deadlock).all()
+
+    return lax.while_loop(running, body, (jnp.int32(0), s))[1]
 
 
 _multi_step_jit = jax.jit(_multi_step, static_argnums=2)
@@ -610,8 +623,9 @@ class LaneResult:
     The counters say what the engine did for the lane: backfill wave
     and settle cascade iterations run while the lane was unfinished
     (the batch's count is the maximum over its lanes), and, the same for
-    every lane of a batch, the lockstep steps the device executed and
-    the host syncs (reads of the done/deadlock flags) it took."""
+    every lane of a batch, the lockstep steps the device executed (the
+    device's own count, which stops at the last lane's end) and the
+    host syncs (reads of the done/deadlock flags) it took."""
 
     jct: dict[str, float]
     cct: dict[str, float]
@@ -624,26 +638,31 @@ class LaneResult:
 
 
 def run_fifo_batch(lanes: Sequence[PackedInstance], *,
-                   steps_per_sync: int = 16,
+                   steps_per_sync: int = 65_536,
                    max_events: int = 5_000_000) -> list[LaneResult]:
     """Advance every lane to completion under the fifo policy; returns
-    per-lane results in input order.  ``steps_per_sync`` bounds how many
-    lockstep events run per host round-trip — finished lanes are masked
-    no-ops, so overshooting a fast lane's final event is harmless.
-    Raises on deadlock (mirroring the numpy core) and on ``max_events``
-    (livelock guard).
+    per-lane results in input order.  Each dispatch runs one device
+    program that steps until every lane is done or deadlocked, at most
+    ``steps_per_sync`` steps; the host then reads the done/deadlock
+    flags once.  ``steps_per_sync`` is thus the window of the
+    ``max_events`` livelock guard, counted on the host.  Its default is
+    far above the steps of every benchmark cell (the longest,
+    DeepSeek-V3's EP64 stage, takes about 3,750), so a call ends in one
+    program, one dispatch and one read; and a livelocked program still
+    returns to the guard within about two minutes at the slowest step
+    measured on a TPU v5e (1.6 ms).  Raises on deadlock (mirroring the
+    numpy core) and on ``max_events``.
 
     Under ``jax.profiler`` the call shows as host spans, on the device
     trace's clock: ``simjax.pack_batch`` (padding and the copies to the
-    device), ``simjax.init`` (first settle), then per window
-    ``simjax.sync`` (the done/deadlock read) and ``simjax.dispatch``
-    (the ``steps_per_sync``-step program), and ``simjax.readback``.  Its
-    device ops carry the named scopes ``simjax.settle``, ``simjax.madd``,
-    ``simjax.backfill`` and ``simjax.horizon`` in their op names.  With
-    the profiler off the spans cost a no-op each.  Counters: see
-    :class:`LaneResult`; ``wave_iters / batch_steps`` is backfill waves
-    per step, ``max(events) / batch_steps`` the share of executed steps
-    some lane needed.  DESIGN.md §17 says how to read them."""
+    device), ``simjax.init`` (first settle), then per program
+    ``simjax.dispatch`` (its enqueue) and ``simjax.sync`` (the
+    done/deadlock read, which waits for it), and ``simjax.readback``.
+    Its device ops carry the named scopes ``simjax.settle``,
+    ``simjax.madd``, ``simjax.backfill`` and ``simjax.horizon`` in their
+    op names.  With the profiler off the spans cost a no-op each.
+    Counters: see :class:`LaneResult`; ``wave_iters / batch_steps`` is
+    backfill waves per step.  DESIGN.md §17 says how to read them."""
     if not lanes:
         return []
     with jax.profiler.TraceAnnotation("simjax.pack_batch"):
@@ -652,6 +671,9 @@ def run_fifo_batch(lanes: Sequence[PackedInstance], *,
         s = _settle_jit(pk, _init_state(pk))
     steps = syncs = 0
     while True:
+        with jax.profiler.TraceAnnotation("simjax.dispatch"):
+            s = _multi_step_jit(pk, s, steps_per_sync)
+        steps += steps_per_sync
         with jax.profiler.TraceAnnotation("simjax.sync"):
             halted = np.asarray(s.done | s.deadlock)
         syncs += 1
@@ -660,9 +682,6 @@ def run_fifo_batch(lanes: Sequence[PackedInstance], *,
         if steps > max_events:
             raise RuntimeError(
                 "batched simulator exceeded max_events — livelock?")
-        with jax.profiler.TraceAnnotation("simjax.dispatch"):
-            s = _multi_step_jit(pk, s, steps_per_sync)
-        steps += steps_per_sync
     with jax.profiler.TraceAnnotation("simjax.readback"):
         if bool(np.asarray(s.deadlock).any()):
             bad = [i for i, d in enumerate(np.asarray(s.deadlock).tolist())
@@ -676,6 +695,7 @@ def run_fifo_batch(lanes: Sequence[PackedInstance], *,
         ev = np.asarray(s.events)
         wv = np.asarray(s.waves)
         cs = np.asarray(s.cascades)
+        n_steps = int(s.steps)
         return [
             LaneResult(
                 jct={n: float(jf[b, i] - p.arrival[i])
@@ -686,7 +706,7 @@ def run_fifo_batch(lanes: Sequence[PackedInstance], *,
                 events=int(ev[b]),
                 wave_iters=int(wv[b]),
                 cascade_iters=int(cs[b]),
-                batch_steps=steps,
+                batch_steps=n_steps,
                 batch_syncs=syncs,
             )
             for b, p in enumerate(lanes)

@@ -18,6 +18,7 @@ import: only one process at a time may load the TPU library.
 
 from __future__ import annotations
 
+import inspect
 import re
 
 import pytest
@@ -30,7 +31,9 @@ from repro.appdag.mixer import build_scenario  # noqa: E402
 from repro.core import simjax  # noqa: E402
 
 LANES = 20
-STEPS = 16
+#: The step program's window, as ``run_fifo_batch`` runs it.
+STEPS = inspect.signature(simjax.run_fifo_batch).parameters[
+    "steps_per_sync"].default
 PHASES = ("simjax.settle", "simjax.madd", "simjax.backfill", "simjax.horizon")
 
 
@@ -110,7 +113,8 @@ def test_step_window_compiles_for_v5e(programs, scenario):
     got = programs(scenario)
     st = got["state"]
     # The loop counters stay 32-bit: 64-bit integers are emulated there.
-    assert st.waves.dtype == st.cascades.dtype == jax.numpy.int32
+    assert (st.waves.dtype == st.cascades.dtype == st.steps.dtype
+            == jax.numpy.int32)
     assert _f64_reduce_windows(got["settle"]) == []
     assert _f64_reduce_windows(got["step"]) == []
     # The named scopes survive the chip's compiler, in the op names a

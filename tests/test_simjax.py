@@ -19,6 +19,7 @@ every other module under ``src/`` free of JAX.
 
 from __future__ import annotations
 
+import inspect
 import random
 import re
 from pathlib import Path
@@ -33,6 +34,7 @@ jnp = jax.numpy
 
 from repro.appdag.mixer import SCENARIOS, build_scenario  # noqa: E402
 from repro.core import Fabric, JobDAG, make_scheduler, simulate  # noqa: E402
+from repro.core import simjax  # noqa: E402
 from repro.core.fabric import Topology, make_topology  # noqa: E402
 from repro.core.simjax import (LaneResult, _demand,  # noqa: E402
                                _pack_batch, pack_instance, run_fifo_batch,
@@ -40,6 +42,9 @@ from repro.core.simjax import (LaneResult, _demand,  # noqa: E402
 
 TOL = 1e-6
 N_SEEDS = 5
+#: ``run_fifo_batch``'s own program window, as the benchmark reads it.
+WINDOW = inspect.signature(run_fifo_batch).parameters[
+    "steps_per_sync"].default
 
 
 def _numpy_oracle(scenario: str, seed: int):
@@ -424,8 +429,8 @@ class TestCounters:
         # First settle (activate both roots, then nothing) and one
         # settle per step (retire a metaflow or none, then nothing).
         assert res.cascade_iters == 2 + 2 + 2
-        # One window of 16 steps, read before and after it.
-        assert (res.batch_steps, res.batch_syncs) == (16, 2)
+        # One program that stops after the 2 steps, read once after it.
+        assert (res.batch_steps, res.batch_syncs) == (2, 1)
 
     @pytest.mark.parametrize("depth", [1, 3])
     def test_chain_cascade_depth(self, depth):
@@ -451,16 +456,77 @@ class TestCounters:
         assert [r.wave_iters for r in res] == [3, 2]
         # The first settle runs the chain's 5 iterations for both lanes.
         assert [r.cascade_iters for r in res] == [5 + 2 + 2, 5 + 2]
-        assert {(r.batch_steps, r.batch_syncs) for r in res} == {(steps, 2)}
+        # The program stops when lane 0 ends, after step 2 of its 4.
+        assert {(r.batch_steps, r.batch_syncs) for r in res} == {(2, 1)}
 
     def test_windows_overshoot(self):
-        """Steps run in whole windows: 16 steps for 2 needed, and a sync
-        before each window and after the last."""
+        """No window overshoots: the steps stop where the last lane
+        ends, 2 steps for 2 needed, and the host reads once after each
+        program."""
         lanes = [pack_instance(Fabric(n_ports=5), self._shared_link())]
-        for per_sync, steps, syncs in ((1, 2, 3), (16, 16, 2)):
+        for per_sync, syncs in ((1, 2), (16, 1), (WINDOW, 1)):
             (res,) = run_fifo_batch(lanes, steps_per_sync=per_sync)
-            assert (res.batch_steps, res.batch_syncs) == (steps, syncs)
+            assert (res.batch_steps, res.batch_syncs) == (2, syncs)
             assert res.wave_iters == 3 and res.cascade_iters == 6
+
+
+class TestHalting:
+    """The step program stops on the device once every lane is done or
+    deadlocked; the host reads the flags once a program."""
+
+    @staticmethod
+    def _lanes():
+        # Four seeds of one scenario: lanes that end at different steps.
+        return [pack_instance(*build_scenario("mixed", seed=s, quick=True,
+                                              lint=False))
+                for s in range(4)]
+
+    def test_results_do_not_depend_on_the_window(self):
+        lanes = self._lanes()
+        runs = {per_sync: [(r.jct, r.cct, r.events, r.wave_iters,
+                            r.cascade_iters)
+                           for r in run_fifo_batch(
+                               lanes, steps_per_sync=per_sync)]
+                for per_sync in (1, 16, WINDOW)}
+        assert len({events for _, _, events, _, _ in runs[WINDOW]}) > 1
+        assert runs[1] == runs[16] == runs[WINDOW]
+
+    @pytest.mark.parametrize("per_sync", [16, WINDOW])
+    def test_program_that_returns_its_state_trips_the_guard(
+            self, monkeypatch, per_sync):
+        calls = []
+
+        def stalled(pk, s, n):
+            calls.append(n)
+            return s
+
+        monkeypatch.setattr(simjax, "_multi_step_jit", stalled)
+        with pytest.raises(RuntimeError, match="max_events"):
+            run_fifo_batch(self._lanes(), steps_per_sync=per_sync,
+                           max_events=64)
+        # The guard counts the host's dispatches: past 64 steps.
+        assert len(calls) == 64 // per_sync + 1
+
+    def test_one_dispatch_of_the_lowered_program(self, monkeypatch):
+        """A call is one dispatch of the program the benchmark lowers
+        from ``_init_state`` at the default window."""
+        real = simjax._multi_step_jit
+        calls = []
+
+        def spy(pk, s, n):
+            calls.append((pk, s, n))
+            return real(pk, s, n)
+
+        monkeypatch.setattr(simjax, "_multi_step_jit", spy)
+        res = run_fifo_batch(self._lanes())
+        assert {r.batch_syncs for r in res} == {1}
+        assert {r.batch_steps for r in res} == {max(r.events for r in res)}
+        ((pk, s, n),) = calls
+        assert n == WINDOW
+        init = simjax._init_state(pk)
+        assert jax.tree.map(jax.typeof, s) == jax.tree.map(jax.typeof, init)
+        assert (real.lower(pk, s, n).as_text()
+                == real.lower(pk, init, WINDOW).as_text())
 
 
 class TestRunnerIntegration:
